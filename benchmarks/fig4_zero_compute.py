@@ -3,10 +3,12 @@
 The paper drives PBox with infinitely fast workers to find the exchange
 ceiling (PCIe-to-memory bound).  Two analogues:
 
-  * SPMD: exchange-only steps (no model compute) measured on 8 host devices
-    across gradient sizes and strategies; derived column reports achieved
-    GB/s of aggregated gradient per step and the modeled per-device wire
-    bytes (flat in worker count for pbox — the scalability claim).
+  * SPMD: exchange-only steps (no model compute) on the devices present,
+    across gradient sizes and strategies, in this process (a child process
+    could not reach a chip its parent holds); derived column reports
+    achieved GB/s of aggregated gradient per step and the modeled
+    per-device wire bytes (flat in worker count for pbox — the
+    scalability claim).
   * Fabric: the in-process PBox fabric fed precomputed gradients (zero
     worker compute), swept over shard counts; the event-clock columns are
     the paper's Fig. 4 shape — pipelined makespan vs the monolithic
@@ -14,47 +16,48 @@ ceiling (PCIe-to-memory bound).  Two analogues:
 """
 from __future__ import annotations
 
-import os
-import subprocess
-import sys
 import time
-from pathlib import Path
 
+import jax
 import jax.numpy as jnp
 
 from benchmarks.common import emit
 
-SCRIPT = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import time
-import jax, jax.numpy as jnp
-from repro.core.exchange import ExchangeConfig, PSExchange
-from repro.core.zero_compute import init_zero_compute_state, make_zero_compute_step
-from repro.launch.mesh import make_mesh
-from repro.optim.optimizers import momentum
 
-mesh = make_mesh((2,2,2), ("pod","data","model"))
-for strat, pod in (("allreduce", None), ("pbox", None), ("pbox_hier", "pod")):
-    for flat in (1<<20, 1<<23):
-        ex = PSExchange(momentum(0.1, 0.9), ExchangeConfig(strat),
-                        ("pod","data","model") if strat != "pbox_hier" else ("pod","data","model"),
-                        pod)
-        step = make_zero_compute_step(mesh, ex, flat)
-        state = init_zero_compute_state(mesh, ex, flat)
-        p = jnp.zeros((flat,)); g = jnp.ones((flat,))
-        p, state = step(p, g, state)  # compile
-        jax.block_until_ready(p)
-        n, t0 = 5, time.perf_counter()
-        for _ in range(n):
-            p, state = step(p, g, state)
-        jax.block_until_ready(p)
-        us = (time.perf_counter()-t0)/n*1e6
-        gbs = flat*4/ (us/1e6) / 1e9
-        mb = ex.modeled_bytes(flat, 2, 4)
-        wire = (mb["push"]+mb["pull"]+(mb["xpod"] or 0.0))/2**20
-        print(f"fig4/{strat}_flat={flat>>20}M,{us:.1f},agg_GBps={gbs:.2f};wire_MiB_dev={wire:.1f}")
-"""
+def _run_spmd_sweep() -> None:
+    """Exchange-only steps per strategy on a ("pod", "data") mesh of every
+    device present (two pods when the count is even)."""
+    from repro.core.exchange import ExchangeConfig, PSExchange
+    from repro.core.zero_compute import (
+        init_zero_compute_state,
+        make_zero_compute_step,
+    )
+    from repro.launch.mesh import make_mesh
+    from repro.optim.optimizers import momentum
+
+    n = len(jax.devices())
+    pods = 2 if n % 2 == 0 else 1
+    mesh = make_mesh((pods, n // pods), ("pod", "data"))
+    for strat in ("allreduce", "pbox", "pbox_hier"):
+        for flat in (1 << 20, 1 << 23):
+            ex = PSExchange(momentum(0.1, 0.9), ExchangeConfig(strat),
+                            ("pod", "data"),
+                            "pod" if strat == "pbox_hier" else None)
+            step = make_zero_compute_step(mesh, ex, flat)
+            state = init_zero_compute_state(mesh, ex, flat)
+            p, g = jnp.zeros((flat,)), jnp.ones((flat,))
+            p, state = step(p, g, state)  # compile
+            jax.block_until_ready(p)
+            iters, t0 = 5, time.perf_counter()
+            for _ in range(iters):
+                p, state = step(p, g, state)
+            jax.block_until_ready(p)
+            us = (time.perf_counter() - t0) / iters * 1e6
+            gbs = flat * 4 / (us / 1e6) / 1e9
+            mb = ex.modeled_bytes(flat, pods, n // pods)
+            wire = (mb["push"] + mb["pull"] + (mb["xpod"] or 0.0)) / 2**20
+            emit(f"fig4/{strat}_flat={flat >> 20}M", us,
+                 f"agg_GBps={gbs:.2f};wire_MiB_dev={wire:.1f};devices={n}")
 
 
 def _run_fabric_sweep() -> None:
@@ -97,15 +100,7 @@ def _run_fabric_sweep() -> None:
 
 
 def run() -> None:
-    env = dict(os.environ)
-    env["PYTHONPATH"] = str(Path(__file__).parent.parent / "src")
-    p = subprocess.run([sys.executable, "-c", SCRIPT], capture_output=True,
-                       text=True, env=env, timeout=900)
-    if p.returncode != 0:
-        emit("fig4/FAILED", 0.0, p.stderr[-200:].replace("\n", " "))
-    else:
-        for line in p.stdout.strip().splitlines():
-            print(line)
+    _run_spmd_sweep()
     _run_fabric_sweep()
 
 

@@ -1,7 +1,8 @@
 #!/usr/bin/env bash
 # Tier-1 verify: the full pytest suite on CPU.  Pallas kernels run in
-# interpret mode off-TPU (the kernels' default), so this needs no
-# accelerator.  Usage: scripts/verify.sh [extra pytest args]
+# interpret mode on any backend that is not a TPU, so this needs no
+# accelerator; chip_smoke.py is the check on the chip.
+# Usage: scripts/verify.sh [extra pytest args]
 set -euo pipefail
 cd "$(dirname "$0")/.."
 

@@ -91,10 +91,9 @@ class ParamSpace:
             )
         if num_owners < 1:
             raise ValueError("num_owners must be >= 1")
-        from repro.compat import tree_leaves_with_path
 
         leaves, treedef = jax.tree.flatten(tree)
-        paths = tree_leaves_with_path(tree)
+        paths = jax.tree.leaves_with_path(tree)
         slots = []
         offset = 0
         for (path, leaf) in paths:

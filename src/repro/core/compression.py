@@ -80,11 +80,11 @@ def encode(cfg: CompressionConfig, slab: jax.Array, ef: jax.Array | None):
         if cfg.error_feedback and ef is not None:
             slab = slab + ef
         q, scale = quantize_chunks(
-            slab, cfg.chunk_elems, use_pallas=cfg.use_pallas, interpret=True
+            slab, cfg.chunk_elems, use_pallas=cfg.use_pallas
         )
         if cfg.error_feedback and ef is not None:
             deq = dequantize_chunks(
-                q, scale, cfg.chunk_elems, use_pallas=cfg.use_pallas, interpret=True
+                q, scale, cfg.chunk_elems, use_pallas=cfg.use_pallas
             )
             new_ef = slab - deq
         else:
@@ -137,12 +137,11 @@ def encode_wire(
         return WirePayload("bf16", wire), new_ef
     if cfg.codec == "int8":
         q, scale = quantize_chunks(
-            slab, cfg.chunk_elems, use_pallas=cfg.use_pallas, interpret=True
+            slab, cfg.chunk_elems, use_pallas=cfg.use_pallas
         )
         if use_ef:
             dec = dequantize_chunks(
                 q, scale, cfg.chunk_elems, use_pallas=cfg.use_pallas,
-                interpret=True,
             )
             new_ef = slab - dec
         else:
@@ -164,7 +163,6 @@ def decode_wire(cfg: CompressionConfig, wp: WirePayload) -> jax.Array:
     if wp.codec == "int8":
         return dequantize_chunks(
             wp.payload, wp.scale, cfg.chunk_elems, use_pallas=cfg.use_pallas,
-            interpret=True,
         )
     raise ValueError(wp.codec)
 
@@ -182,7 +180,7 @@ def decode(cfg: CompressionConfig, payload: tuple) -> jax.Array:
     if cfg.codec == "int8":
         q, scale = payload
         return dequantize_chunks(
-            q, scale, cfg.chunk_elems, use_pallas=cfg.use_pallas, interpret=True
+            q, scale, cfg.chunk_elems, use_pallas=cfg.use_pallas
         )
     raise ValueError(cfg.codec)
 
@@ -208,11 +206,10 @@ def roundtrip(
         dec = slab.astype(jnp.bfloat16).astype(jnp.float32)
     elif cfg.codec == "int8":
         q, scale = quantize_chunks(
-            slab, cfg.chunk_elems, use_pallas=cfg.use_pallas, interpret=True
+            slab, cfg.chunk_elems, use_pallas=cfg.use_pallas
         )
         dec = dequantize_chunks(
             q, scale, cfg.chunk_elems, use_pallas=cfg.use_pallas,
-            interpret=True,
         )
     else:
         raise ValueError(cfg.codec)

@@ -41,8 +41,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro import compat
-
 from repro.core import compression as comp
 from repro.core.chunking import ParamSpace
 from repro.core.compression import CompressionConfig
@@ -56,13 +54,11 @@ class ExchangeConfig:
     chunk_elems: int = 8192
     compression: CompressionConfig = CompressionConfig()
     pull_dtype: Any = None  # e.g. jnp.bfloat16 to halve pull bytes
-    # On TPU the fused Pallas kernel applies (use_pallas=True, interpret=False).
-    # Default False: on this CPU container interpret-mode Pallas lowers to a
-    # while-per-grid-step that distorts dry-run cost analysis; the jnp path
-    # is numerically identical (tests/test_kernels.py) and XLA fuses it into
-    # the same single-pass update the kernel implements.
+    # use_pallas=True applies the slab update with the fused_agg_opt kernel
+    # (compiled on a TPU, interpreted elsewhere); the default jnp path is
+    # numerically identical (tests/test_kernels.py) and XLA fuses it into
+    # the same single-pass update.  Which is faster on the chip is open.
     use_pallas: bool = False
-    interpret: bool = True
 
 
 class PSExchange:
@@ -130,7 +126,7 @@ class PSExchange:
     def _num_workers(self) -> Any:
         n = 1
         for a in self.worker_axes:
-            n *= compat.axis_size(a)
+            n *= lax.axis_size(a)
         return n
 
     def device_update(
@@ -156,7 +152,6 @@ class PSExchange:
                 lr_scale,
                 average=False,
                 use_pallas=cfg.use_pallas,
-                interpret=cfg.interpret,
             )
             return new_p, {"slots": new_slots, "ef": state["ef"], "step": step}
 
@@ -179,7 +174,6 @@ class PSExchange:
                 lr_scale,
                 average=False,
                 use_pallas=cfg.use_pallas,
-                interpret=cfg.interpret,
             )
             # pull: one all-gather of updated slabs
             pulled = new_slab
@@ -194,8 +188,8 @@ class PSExchange:
             data_axes = self.owner_axes
             n_data = 1
             for a in data_axes:
-                n_data *= compat.axis_size(a)
-            n_pod = compat.axis_size(pod)
+                n_data *= lax.axis_size(a)
+            n_pod = lax.axis_size(pod)
             # stage 1: rack-local aggregation (reduce-scatter within pod)
             slab = lax.psum_scatter(
                 gflat, data_axes, scatter_dimension=0, tiled=True
@@ -230,7 +224,6 @@ class PSExchange:
                 lr_scale,
                 average=False,
                 use_pallas=cfg.use_pallas,
-                interpret=cfg.interpret,
             )
             # pull stays inside the pod: updates are replicated across pods
             pulled = new_slab

@@ -272,7 +272,6 @@ class PBoxShard:
             jnp.int32(step),
             average=average,
             use_pallas=self.use_pallas,
-            interpret=True,
         )
         shape = (self.num_chunks, self.space.chunk_elems)
         self.params = new_p[:n].reshape(shape)
@@ -308,7 +307,6 @@ class PBoxShard:
             codec=codec,
             chunk_elems=self.space.chunk_elems,
             average=average,
-            interpret=True,
         )
         shape = (self.num_chunks, self.space.chunk_elems)
         self.params = new_p.reshape(shape)

@@ -25,8 +25,6 @@ import dataclasses
 import jax
 from jax import lax
 
-from repro import compat
-
 
 def hierarchical_psum(x: jax.Array, inner_axes, outer_axis: str | None):
     """psum factored as inner reduce-scatter + outer all-reduce + inner
@@ -45,9 +43,9 @@ def hierarchical_psum(x: jax.Array, inner_axes, outer_axis: str | None):
 def hierarchical_pmean(x: jax.Array, inner_axes, outer_axis: str | None):
     n = 1
     for a in (inner_axes if isinstance(inner_axes, (tuple, list)) else (inner_axes,)):
-        n *= compat.axis_size(a)
+        n *= lax.axis_size(a)
     if outer_axis is not None:
-        n *= compat.axis_size(outer_axis)
+        n *= lax.axis_size(outer_axis)
     return hierarchical_psum(x, inner_axes, outer_axis) / n
 
 

@@ -12,7 +12,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.exchange import PSExchange
 
 
@@ -43,7 +42,7 @@ def make_zero_compute_step(
         new_p, new_state = exchange.device_update(gflat, pflat, state)
         return new_p, new_state
 
-    shmap = shard_map(
+    shmap = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(), P(), state_specs),

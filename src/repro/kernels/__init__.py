@@ -12,5 +12,23 @@ program) / ``ops.py`` (validated, jit'd public surface) package:
   push payloads, bit-identical to the unfused pipeline.
 
 Import from each family's package (``repro.kernels.<family>``); this
-namespace package deliberately re-exports nothing.
+package re-exports nothing.  It holds the one rule for how every kernel
+runs: compiled on a TPU and in the Pallas interpreter on any other backend
+(:func:`interpret_mode`).  A kernel that fails to compile for the TPU
+raises; nothing falls back to the interpreter or to ``ref.py``.
 """
+from __future__ import annotations
+
+import jax
+
+
+def interpret_mode(interpret: bool | None = None) -> bool:
+    """Whether a Pallas kernel runs in interpret mode.
+
+    ``interpret`` wins where the caller gives it (the TPU compile tests
+    pass ``False`` to compile for a described chip from a CPU host);
+    ``None`` means interpret exactly when the default backend is not a
+    TPU."""
+    if interpret is not None:
+        return interpret
+    return jax.default_backend() != "tpu"

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import interpret_mode
 from repro.kernels.embedding_bag.kernel import embedding_bag_pallas
 from repro.kernels.embedding_bag.ref import embedding_bag_ref
 
@@ -33,12 +34,12 @@ def _dispatch(
     weights: jax.Array,
     mode: str,
     use_pallas: bool,
-    interpret: bool,
+    interpret: bool | None,
 ) -> jax.Array:
     indices = jnp.clip(indices, 0, table.shape[0] - 1)
     if use_pallas:
         return embedding_bag_pallas(table, indices, weights, mode,
-                                    interpret=interpret)
+                                    interpret=interpret_mode(interpret))
     return embedding_bag_ref(table, indices, weights, mode)
 
 
@@ -49,7 +50,7 @@ def embedding_bag(
     mode: str = "sum",
     *,
     use_pallas: bool = False,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Weighted embedding-bag lookup: bags of table rows, summed or meaned.
 

@@ -167,7 +167,7 @@ def fused_agg_opt_pallas(
     spec: OptimizerSpec,
     *,
     average: bool = True,
-    interpret: bool = True,
+    interpret: bool,
     block_target: int = 256,
 ) -> tuple[jax.Array, tuple]:
     """Pallas fused aggregate+optimize over an (K, N) gradient slab.

@@ -1,6 +1,7 @@
 """jit'd public wrapper for the fused aggregate+optimize kernel.
 
-Chooses the Pallas kernel (interpret=True off-TPU) or the pure-jnp reference,
+Chooses the Pallas kernel (compiled on a TPU, interpreted elsewhere — see
+``repro.kernels.interpret_mode``) or the pure-jnp reference,
 and computes the traced scalar packet (lr*schedule, Adam bias corrections)
 outside the kernel.
 """
@@ -11,6 +12,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.fused_agg_opt.kernel import fused_agg_opt_pallas
 from repro.kernels.fused_agg_opt.ref import fused_aggregate_update_ref
 from repro.optim.optimizers import OptimizerSpec
@@ -54,7 +56,7 @@ def fused_aggregate_update(
     *,
     average: bool = True,
     use_pallas: bool = True,
-    interpret: bool = True,
+    interpret: bool | None = None,
     block_target: int = 256,
 ) -> tuple[jax.Array, tuple]:
     """Aggregate K worker gradient slabs and apply the server optimizer.
@@ -76,6 +78,6 @@ def fused_aggregate_update(
         scalars,
         spec,
         average=average,
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
         block_target=block_target,
     )

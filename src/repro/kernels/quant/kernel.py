@@ -4,6 +4,10 @@ One grid step handles one PS chunk (chunk_elems elements viewed as
 (chunk_elems/128, 128)); the chunk's amax reduction, scale computation and
 rounding all happen in a single VMEM pass.  Scales are emitted as one f32 per
 chunk (the per-chunk metadata the paper's PS keeps besides the payload).
+
+The scale operand is viewed as (C, 1, 1) with (1, 1, 1) blocks: the TPU
+compiler takes a block whose last two dimensions equal the array's, where a
+(1, 1) block of a (C, 1) array is refused as unaligned to the (8, 128) tile.
 """
 from __future__ import annotations
 
@@ -20,16 +24,15 @@ def _quant_kernel(x_ref, q_ref, s_ref):
     scale = jnp.where(amax > 0, amax / 127.0, 1.0)
     q = jnp.clip(jnp.round(x / scale), -127, 127)
     q_ref[...] = q.astype(jnp.int8)
-    s_ref[0, 0] = scale
+    s_ref[...] = jnp.full(s_ref.shape, scale, jnp.float32)
 
 
 def _dequant_kernel(q_ref, s_ref, x_ref):
-    scale = s_ref[0, 0]
-    x_ref[...] = q_ref[...].astype(jnp.float32) * scale
+    x_ref[...] = q_ref[...].astype(jnp.float32) * s_ref[0]
 
 
 def quantize_chunks_pallas(
-    x: jax.Array, chunk_elems: int, *, interpret: bool = True
+    x: jax.Array, chunk_elems: int, *, interpret: bool
 ) -> tuple[jax.Array, jax.Array]:
     """Pallas per-chunk symmetric int8 quantize of an (N,) f32 slab.
 
@@ -48,11 +51,11 @@ def quantize_chunks_pallas(
         in_specs=[pl.BlockSpec((rows, LANES), lambda i: (i, 0))],
         out_specs=[
             pl.BlockSpec((rows, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, 1), lambda i: (i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((c * rows, LANES), jnp.int8),
-            jax.ShapeDtypeStruct((c, 1), jnp.float32),
+            jax.ShapeDtypeStruct((c, 1, 1), jnp.float32),
         ],
         interpret=interpret,
     )(x2)
@@ -60,7 +63,7 @@ def quantize_chunks_pallas(
 
 
 def dequantize_chunks_pallas(
-    q: jax.Array, scale: jax.Array, chunk_elems: int, *, interpret: bool = True
+    q: jax.Array, scale: jax.Array, chunk_elems: int, *, interpret: bool
 ) -> jax.Array:
     """Pallas per-chunk int8 dequantize: ``f32(q) * scale[chunk]``.
 
@@ -71,13 +74,13 @@ def dequantize_chunks_pallas(
     c = n // chunk_elems
     rows = chunk_elems // LANES
     q2 = q.reshape(c * rows, LANES)
-    s2 = scale.reshape(c, 1)
+    s2 = scale.reshape(c, 1, 1)
     x2 = pl.pallas_call(
         _dequant_kernel,
         grid=(c,),
         in_specs=[
             pl.BlockSpec((rows, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, 1), lambda i: (i, 0, 0)),
         ],
         out_specs=pl.BlockSpec((rows, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((c * rows, LANES), jnp.float32),

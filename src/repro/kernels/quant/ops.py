@@ -13,6 +13,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.quant.kernel import (
     LANES,
     dequantize_chunks_pallas,
@@ -33,7 +34,8 @@ def _check_chunking(n: int, chunk_elems: int) -> None:
 
 
 @partial(jax.jit, static_argnames=("chunk_elems", "use_pallas", "interpret"))
-def quantize_chunks(x, chunk_elems: int, *, use_pallas: bool = True, interpret: bool = True):
+def quantize_chunks(x, chunk_elems: int, *, use_pallas: bool = True,
+                    interpret: bool | None = None):
     """Quantize a flat f32 slab to (int8 payload, per-chunk f32 scales)."""
     if x.ndim != 1:
         raise ValueError(f"expected a flat slab, got shape {x.shape}")
@@ -42,11 +44,13 @@ def quantize_chunks(x, chunk_elems: int, *, use_pallas: bool = True, interpret: 
     _check_chunking(x.shape[0], chunk_elems)
     if not use_pallas:
         return quantize_chunks_ref(x, chunk_elems)
-    return quantize_chunks_pallas(x, chunk_elems, interpret=interpret)
+    return quantize_chunks_pallas(x, chunk_elems,
+                                  interpret=interpret_mode(interpret))
 
 
 @partial(jax.jit, static_argnames=("chunk_elems", "use_pallas", "interpret"))
-def dequantize_chunks(q, scale, chunk_elems: int, *, use_pallas: bool = True, interpret: bool = True):
+def dequantize_chunks(q, scale, chunk_elems: int, *, use_pallas: bool = True,
+                      interpret: bool | None = None):
     """Decode an (int8 payload, per-chunk f32 scales) pair back to f32."""
     if q.ndim != 1:
         raise ValueError(f"expected a flat payload, got shape {q.shape}")
@@ -60,4 +64,5 @@ def dequantize_chunks(q, scale, chunk_elems: int, *, use_pallas: bool = True, in
             f"{scale.shape}")
     if not use_pallas:
         return dequantize_chunks_ref(q, scale, chunk_elems)
-    return dequantize_chunks_pallas(q, scale, chunk_elems, interpret=interpret)
+    return dequantize_chunks_pallas(q, scale, chunk_elems,
+                                    interpret=interpret_mode(interpret))

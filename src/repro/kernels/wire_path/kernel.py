@@ -9,9 +9,12 @@ int8 payload + per-chunk f32 scales, bf16, or raw f32 — so the decoded
 gradients live only in VMEM and each HBM buffer is touched exactly once.
 
 Layout: K streams of C chunks (chunk_elems = R*128 elements each) arrive
-as a (K, C*R, 128) payload in wire dtype, plus a (K, C) f32 scale operand
-for int8.  One grid step covers a *block* of ``cb`` chunks (cb divides C,
-so no padding is ever needed); params/optimizer state ride in matching
+as a (K, C*R, 128) payload in wire dtype, plus a per-chunk f32 scale
+operand for int8, viewed as (C, K, 1) so that its (cb, K, 1) block ends in
+the array's own last two dimensions (the TPU compiler refuses a (K, cb)
+block of a (K, C) array, whose lane dimension is not a multiple of 128).
+One grid step covers a *block* of ``cb`` chunks (cb divides C, so no
+padding is ever needed); params/optimizer state ride in matching
 (cb*R, 128) f32 blocks.
 
 Double-buffered chunk staging: inside a grid step, chunks pipeline
@@ -95,7 +98,7 @@ def _wire_kernel(
         # (q.astype(f32) * scale for int8; dtype widening otherwise)
         blk = pay_ref[:, j * r : (j + 1) * r, :].astype(jnp.float32)
         if codec == "int8":
-            blk = blk * scale_ref[:, j].reshape(k, 1, 1)
+            blk = blk * scale_ref[j].reshape(k, 1, 1)
         # the fence pins the decoded value to rounded f32 before the fold
         # reads it back — the staging slot is the kernel's stand-in for
         # the unfused path's HBM materialization, so it must be a real
@@ -157,7 +160,7 @@ def wire_fused_pallas(
     codec: str,
     chunk_elems: int,
     average: bool = True,
-    interpret: bool = True,
+    interpret: bool,
     block_chunks: int | None = None,
 ) -> tuple[jax.Array, tuple]:
     """Run the fused wire kernel; returns ``(new_param, new_state)``."""
@@ -189,8 +192,8 @@ def wire_fused_pallas(
     if codec == "int8":
         if scales is None:
             raise ValueError("int8 wire streams need per-chunk scales")
-        in_specs.append(pl.BlockSpec((k, cb), lambda i: (0, i)))
-        operands.append(scales.reshape(k, c))
+        in_specs.append(pl.BlockSpec((cb, k, 1), lambda i: (i, 0, 0)))
+        operands.append(scales.reshape(k, c).T.reshape(c, k, 1))
 
     n_state = spec.num_state_slots
     in_specs += [slab_spec] * (1 + n_state)

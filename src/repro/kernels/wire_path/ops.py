@@ -26,6 +26,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.fused_agg_opt.ops import fused_aggregate_update, scalar_packet
 from repro.kernels.quant.ops import dequantize_chunks
 from repro.kernels.wire_path.kernel import LANES, wire_fused_pallas
@@ -84,7 +85,7 @@ def fused_wire_update(
     chunk_elems: int,
     average: bool = True,
     use_pallas: bool = True,
-    interpret: bool = True,
+    interpret: bool | None = None,
     block_chunks: int | None = None,
 ) -> tuple[jax.Array, tuple]:
     """Apply K wire streams to ``param``/``state`` in a single pass.
@@ -118,7 +119,7 @@ def fused_wire_update(
         codec=codec,
         chunk_elems=chunk_elems,
         average=average,
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
         block_chunks=block_chunks,
     )
 
@@ -136,7 +137,7 @@ def unfused_wire_update(
     chunk_elems: int,
     average: bool = True,
     use_pallas: bool = True,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, tuple]:
     """The unfused three-program pipeline (decode -> HBM -> agg+opt).
 

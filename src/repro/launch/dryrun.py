@@ -1,7 +1,3 @@
-import os
-
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 Proves the production sharding config is coherent without hardware: for each
@@ -9,7 +5,10 @@ cell we lower the full step with ShapeDtypeStruct inputs (no allocation),
 compile the SPMD partition, and record memory_analysis / cost_analysis /
 per-collective byte counts for EXPERIMENTS.md §Dry-run and §Roofline.
 
-Usage:
+The production meshes need 512 devices; on a CPU host the caller makes
+them virtual before JAX starts:
+
+  export XLA_FLAGS=--xla_force_host_platform_device_count=512
   PYTHONPATH=src python -m repro.launch.dryrun --arch gemma3-1b --shape train_4k
   PYTHONPATH=src python -m repro.launch.dryrun --all [--multipod both]
 Results are cached as JSON under artifacts/dryrun/.

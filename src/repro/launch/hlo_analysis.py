@@ -38,8 +38,11 @@ _COLLECTIVES = (
 )
 
 _SHAPE_RE = re.compile(r"((?:f|bf|s|u|pred|c|token)[\w]*)\[([\d,]*)\]")
+# a tuple type may hold one level of parentheses: TPU layouts such as
+# ``f32[8]{0:T(1024)}``
 _DEF_RE = re.compile(
-    r"^(?:ROOT\s+)?%([\w.\-]+)\s*=\s*(\([^()]*\)|[\w]+\[[\d,]*\](?:\{[^}]*\})?)\s*"
+    r"^(?:ROOT\s+)?%([\w.\-]+)\s*=\s*"
+    r"(\((?:[^()]|\([^()]*\))*\)|[\w]+\[[\d,]*\](?:\{[^}]*\})?)\s*"
     r"([\w\-]+)\("
 )
 _HDR_RE = re.compile(r"^(ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->\s*.+\{\s*$")

@@ -2,7 +2,7 @@
 this module never touches jax device state)."""
 from __future__ import annotations
 
-from repro import compat
+import jax
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -10,12 +10,15 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod: 2 pods x 256 = 512 chips ("pod", "data", "model")."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: tuple, axes: tuple):
-    """Arbitrary mesh (tests / smoke runs / examples)."""
-    return compat.make_mesh(shape, axes)
+    """Arbitrary mesh (tests / smoke runs / examples), every axis Auto: the
+    step builders place data with explicit shardings, and ``jax.make_mesh``
+    would otherwise make the axes Explicit."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def worker_axes(mesh) -> tuple:

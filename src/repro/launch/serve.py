@@ -10,6 +10,8 @@ flat space at the stamped round.
   PYTHONPATH=src python -m repro.launch.serve --arch gemma3-1b --mesh 1x2 \
       --tokens 16 --batch 4 --source fabric --train-rounds 2
 
+The mesh must fit the devices present (``--mesh 1x2`` needs two).
+
 Sources:
   fabric      build a PBoxFabric over the model, run ``--train-rounds``
               rounds of (deterministic, seeded) synthetic-gradient
@@ -28,7 +30,6 @@ timings) so tests can drive it in-process; the CLI prints the same.
 from __future__ import annotations
 
 import argparse
-import os
 import time
 
 
@@ -171,22 +172,19 @@ def main(argv=None) -> dict:
     args = build_argparser().parse_args(argv)
 
     d, m = (int(x) for x in args.mesh.split("x"))
-    if d * m > 1:
-        os.environ.setdefault(
-            "XLA_FLAGS", f"--xla_force_host_platform_device_count={d*m}"
-        )
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from repro.configs.registry import get_arch
     from repro.core.chunking import ParamSpace
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_mesh
     from repro.models.common import Dist
     from repro.models import transformer as T
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
 
+    enable_compile_cache()
     mesh = make_mesh((d, m), ("data", "model"))
     arch = get_arch(args.arch)
     cfg = arch.smoke_config
@@ -217,11 +215,11 @@ def main(argv=None) -> dict:
     cache_spec = {"k": P(None, wa, "model" if m > 1 else None),
                   "v": P(None, wa, "model" if m > 1 else None)}
 
-    pf = jax.jit(shard_map(
+    pf = jax.jit(jax.shard_map(
         lambda p, t: T.prefill(p, t, cfg, dist, tp, max_seq),
         mesh=mesh, in_specs=(specs, bspec),
         out_specs=(bspec, cache_spec), check_vma=False))
-    dc = jax.jit(shard_map(
+    dc = jax.jit(jax.shard_map(
         lambda p, t, c, pos: T.decode_step(p, t, c, pos, cfg, dist, tp),
         mesh=mesh, in_specs=(specs, bspec, cache_spec, P()),
         out_specs=(bspec, cache_spec), check_vma=False))

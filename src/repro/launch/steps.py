@@ -15,8 +15,6 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
-
 from repro.configs.registry import ArchDef, ShapeCell, get_arch
 from repro.core.exchange import ExchangeConfig, PSExchange
 from repro.launch import mesh as meshlib
@@ -68,8 +66,6 @@ def make_exchange(mesh, family: str, strategy: str = "pbox",
                   exchange_cfg: ExchangeConfig | None = None) -> PSExchange:
     wa = meshlib.worker_axes(mesh)
     pa = meshlib.pod_axis(mesh)
-    if family == "vision":
-        wa = tuple(mesh.axis_names)  # pure DP over every axis
     cfg = exchange_cfg or ExchangeConfig(strategy=strategy)
     if cfg.strategy == "pbox_hier" and pa is None:
         cfg = dataclasses.replace(cfg, strategy="pbox")
@@ -156,7 +152,7 @@ def build_lm_prefill(arch: ArchDef, cell: ShapeCell, mesh,
         return T.prefill(params, tokens, cfg, dist, tp, s)
 
     cache_spec = {"k": P(None, wa, "model"), "v": P(None, wa, "model")}
-    shmap = shard_map(
+    shmap = jax.shard_map(
         fn, mesh=mesh, in_specs=(specs, P(wa)),
         out_specs=(P(wa), cache_spec), check_vma=False)
     n_act = cfg.active_param_count()
@@ -190,7 +186,7 @@ def build_lm_decode(arch: ArchDef, cell: ShapeCell, mesh,
 
     cache_spec = {"k": P(None, None if batch_rep else wa, "model"),
                   "v": P(None, None if batch_rep else wa, "model")}
-    shmap = shard_map(
+    shmap = jax.shard_map(
         fn, mesh=mesh, in_specs=(specs, bspec, cache_spec, P()),
         out_specs=(bspec, cache_spec), check_vma=False)
     cache_shape = (cfg.n_layers, gb, s, cfg.n_kv_heads, cfg.head_dim)
@@ -236,7 +232,7 @@ def build_lm_decode_long(arch: ArchDef, cell: ShapeCell, mesh,
         cache_specs.append(sp)
         cache_args.append({"k": _sds(mesh, shape, cfg.dtype, sp["k"]),
                            "v": _sds(mesh, shape, cfg.dtype, sp["v"])})
-    shmap = shard_map(
+    shmap = jax.shard_map(
         fn, mesh=mesh, in_specs=(specs, P(None), cache_specs, P()),
         out_specs=(P(None), cache_specs), check_vma=False)
     n_act = cfg.active_param_count()
@@ -342,8 +338,8 @@ def build_recsys_cell(arch: ArchDef, cell: ShapeCell, mesh,
         def fn(params, batch):
             return score_f(params, batch, cfg, dist)
 
-        shmap = shard_map(fn, mesh=mesh, in_specs=(specs, batch_spec),
-                              out_specs=out_spec, check_vma=False)
+        shmap = jax.shard_map(fn, mesh=mesh, in_specs=(specs, batch_spec),
+                                  out_specs=out_spec, check_vma=False)
         pargs = _abstract_tree(mesh, gshape, specs)
         return CellPlan(arch.arch_id, cell.name, "serve", jax.jit(shmap),
                         (pargs, batch_t),
@@ -361,8 +357,8 @@ def build_recsys_cell(arch: ArchDef, cell: ShapeCell, mesh,
             return RS.bulk_retrieval(params, batch, tower_f, "t0",
                                      cfg.embed_dim, cfg, dist)
 
-        shmap = shard_map(fn, mesh=mesh, in_specs=(specs, batch_spec),
-                              out_specs=P(all_ax), check_vma=False)
+        shmap = jax.shard_map(fn, mesh=mesh, in_specs=(specs, batch_spec),
+                                  out_specs=P(all_ax), check_vma=False)
         pargs = _abstract_tree(mesh, gshape, specs)
         return CellPlan(arch.arch_id, cell.name, "retrieval", jax.jit(shmap),
                         (pargs, batch_t),
@@ -594,11 +590,17 @@ def _gnn_flops(cfg: EQ.EquiformerConfig, n: int, e: int) -> float:
 
 def build_vision_train(arch: ArchDef, cell: ShapeCell, mesh,
                        exchange: PSExchange | None, smoke: bool = False) -> CellPlan:
+    if mesh.shape["model"] != 1:
+        raise ValueError(f"{arch.arch_id} is pure data parallel: its mesh "
+                         f"needs a model axis of 1, got {dict(mesh.shape)}")
     cfg = arch.smoke_config if smoke else arch.config
-    wa = tuple(mesh.axis_names)
+    wa = meshlib.worker_axes(mesh)
     dist = Dist(model_axis=None, data_axes=wa, tp=1)
-    gb = cell.params["global_batch"] if not smoke else len(jax.devices()) * 2
+    gb = cell.params["global_batch"] if not smoke else mesh.size * 2
     img = cell.params.get("img", 224) if not smoke else 32
+    # gradient accumulation holds the published global batch within one
+    # chip's HBM; smoke runs keep it so CPU tests take the same scan path
+    mb = (arch.microbatches or {}).get(cell.name, 1)
     exchange = exchange or make_exchange(mesh, "vision")
     gshape = jax.eval_shape(lambda: RN.init_params(cfg, jax.random.PRNGKey(0)))
     specs = jax.tree.map(lambda _: P(), gshape,
@@ -610,6 +612,7 @@ def build_vision_train(arch: ArchDef, cell: ShapeCell, mesh,
         mesh, loss_fn=lambda p, b, d: RN.loss_fn(p, b, cfg, d),
         param_specs=specs, sync_tags=tags, global_param_template=gshape,
         exchange=exchange, dist=dist, batch_spec=bspec, loss_div_tp=False,
+        microbatches=mb,
     )
     args = (
         _sds(mesh, (ng, space.flat_elems), jnp.float32, sspecs["pflat"]),
@@ -622,7 +625,7 @@ def build_vision_train(arch: ArchDef, cell: ShapeCell, mesh,
     return CellPlan(arch.arch_id, cell.name, "train", step, args, {
         "space": space, "sspecs": sspecs, "n_groups": ng,
         "model_flops": 3 * 2 * 4.1e9 * gb,  # ~4.1 GMACs/img fwd
-        "examples": gb})
+        "examples": gb, "microbatches": mb})
 
 
 # ===========================================================================

@@ -29,8 +29,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
-from repro.compat import shard_map
 
 from repro.core.exchange import PSExchange
 from repro.models.common import Dist
@@ -83,7 +81,7 @@ def sparse_table_update(
         cot_all = lax.all_gather(cot, worker_axes, axis=0, tiled=True)
         nw = 1
         for a in worker_axes:
-            nw *= compat.axis_size(a)
+            nw *= lax.axis_size(a)
     else:
         ids_all, cot_all, nw = ids, cot, 1
     scale = jnp.asarray(lr, jnp.float32) / nw
@@ -162,6 +160,6 @@ def make_sparse_recsys_train_step(
     in_specs = (sspecs["pflat"], sspecs["slots"], None, P(), table_specs,
                 batch_spec)
     out_specs = (sspecs["pflat"], sspecs["slots"], None, P(), table_specs, P())
-    shmap = shard_map(device_step, mesh=mesh, in_specs=in_specs,
+    shmap = jax.shard_map(device_step, mesh=mesh, in_specs=in_specs,
                           out_specs=out_specs, check_vma=False)
     return jax.jit(shmap, donate_argnums=(0, 1, 4)), space, sspecs

@@ -25,7 +25,6 @@ from jax import lax
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.chunking import ParamSpace
 from repro.core.exchange import PSExchange
 from repro.core.fabric import ServerStats
@@ -312,7 +311,7 @@ def make_ps_train_step(
         sspecs["step"],
         P(),
     )
-    shmap = shard_map(
+    shmap = jax.shard_map(
         device_step, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=False,
     )

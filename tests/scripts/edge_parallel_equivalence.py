@@ -2,13 +2,13 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import dataclasses
 import jax, jax.numpy as jnp, numpy as np
-from repro import compat
+from repro.launch.mesh import make_mesh
 from jax.sharding import PartitionSpec as P
 from repro.models.gnn import equiformer_v2 as EQ
 from repro.models.common import Dist
 from repro.data.graphs import random_graph
 
-mesh = compat.make_mesh((2,4), ("data","model"))
+mesh = make_mesh((2,4), ("data","model"))
 cfg0 = EQ.EquiformerConfig("t", n_layers=2, channels=16, l_max=2, m_max=1, n_heads=4,
                            n_rbf=8, d_in=12, n_out=5, task="node_class", remat=False)
 cfg_ep = dataclasses.replace(cfg0, edge_parallel=True)
@@ -27,7 +27,7 @@ bspec = {k: (P("model") if k in ("edge_src","edge_dst","edge_mask","wigner","rbf
 def f(p, g):
     loss, met = EQ.loss_fn(p, g, cfg_ep, dist)
     return loss * 4  # undo /tp for comparison
-fj = jax.jit(compat.shard_map(f, mesh=mesh, in_specs=(specs, bspec), out_specs=P(), check_vma=False))
+fj = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=(specs, bspec), out_specs=P(), check_vma=False))
 lep = fj(p0, gj)
 print("ref:", float(ref), "edge-parallel:", float(lep))
 np.testing.assert_allclose(float(ref), float(lep), rtol=1e-5)
@@ -39,7 +39,7 @@ def gradf(p, g):
     gr = jax.grad(lambda p_: EQ.loss_fn(p_, g, cfg_ep, dist)[0])(p)
     gr = apply_grad_sync(gr, tags, dist)
     return gr
-gj_fn = jax.jit(compat.shard_map(gradf, mesh=mesh, in_specs=(specs, bspec),
+gj_fn = jax.jit(jax.shard_map(gradf, mesh=mesh, in_specs=(specs, bspec),
                out_specs=jax.tree.map(lambda _: P(), specs), check_vma=False))
 g_ep = gj_fn(p0, gj)
 g_ref = jax.grad(lambda p_: EQ.loss_fn(p_, gj, cfg0, Dist.none())[0])(p0)

@@ -1,14 +1,14 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
-from repro import compat
+from repro.launch.mesh import make_mesh
 from jax.sharding import PartitionSpec as P
 
 from repro.core.exchange import ExchangeConfig, PSExchange
 from repro.core.compression import CompressionConfig
 from repro.optim.optimizers import adam, make_optimizer
 
-mesh = compat.make_mesh((2,2,2), ("pod","data","model"))
+mesh = make_mesh((2,2,2), ("pod","data","model"))
 spec = adam(1e-2)
 
 # toy model: params = dict of two tensors; grads differ per worker (batch-sharded)
@@ -34,7 +34,7 @@ def run_strategy(strategy, worker_axes, pod_axis, codec="none", steps=3):
     n_owner = max(space.num_owners, 1) if strategy != "allreduce" else 1
     slab_spec = P(ex.owner_axes) if ex.owner_axes else P()
     slots_specs = tuple(slab_spec for _ in range(spec.num_state_slots))
-    f = jax.jit(compat.shard_map(body, mesh=mesh,
+    f = jax.jit(jax.shard_map(body, mesh=mesh,
         in_specs=(P(), slots_specs, P()),
         out_specs=(P(), slots_specs), check_vma=False))
     pflat0 = space.flatten(params)

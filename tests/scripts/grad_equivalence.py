@@ -1,7 +1,7 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
-from repro import compat
+from repro.launch.mesh import make_mesh
 from jax.sharding import PartitionSpec as P
 from repro.models import transformer as T
 from repro.models.common import Dist
@@ -9,7 +9,7 @@ from repro.core.exchange import ExchangeConfig, PSExchange
 from repro.optim.optimizers import sgd, make_optimizer
 from repro.runtime.trainer import make_ps_train_step, init_train_state
 
-mesh = compat.make_mesh((2,4), ("data","model"))
+mesh = make_mesh((2,4), ("data","model"))
 TP = 4
 spec = sgd(1e-1)
 

@@ -4,7 +4,7 @@ import os
 
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax
-from repro import compat
+from repro.launch.mesh import make_mesh
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
@@ -14,7 +14,7 @@ from repro.core.hierarchy import hierarchical_pmean, hierarchical_psum
 from repro.core.zero_compute import init_zero_compute_state, make_zero_compute_step
 from repro.optim.optimizers import momentum
 
-mesh = compat.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 
 # hierarchical psum == flat psum
 def f(x):
@@ -23,7 +23,7 @@ def f(x):
     c = hierarchical_pmean(x, ("data",), "pod")
     return a, b, c
 
-g = jax.jit(compat.shard_map(f, mesh=mesh, in_specs=P(("pod", "data")),
+g = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P(("pod", "data")),
                           out_specs=(P(None), P(None), P(None)), check_vma=False))
 x = jnp.arange(32.0).reshape(4, 8)
 a, b, c = g(x.reshape(-1))

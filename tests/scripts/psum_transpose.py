@@ -1,9 +1,9 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp, numpy as np
-from repro import compat
+from repro.launch.mesh import make_mesh
 from jax.sharding import PartitionSpec as P
-mesh = compat.make_mesh((4,), ("model",))
+mesh = make_mesh((4,), ("model",))
 
 # per-device: y = psum(x * w_local); loss_local = y * c_local (device-varying)
 # truth: L_total interpretation? We compute grad of the PER-DEVICE loss function
@@ -19,7 +19,7 @@ def gradfn(w, c):
 
 w = jnp.arange(1., 5.)  # w_j = j+1 per device
 c = jnp.array([10., 20., 30., 40.])
-g = jax.jit(compat.shard_map(lambda w, c: jax.grad(lambda w_: f(w_[0], c[0]))(w), mesh=mesh,
+g = jax.jit(jax.shard_map(lambda w, c: jax.grad(lambda w_: f(w_[0], c[0]))(w), mesh=mesh,
     in_specs=(P("model"), P("model")), out_specs=P("model"), check_vma=False))(w, c)
 print("per-device dw:", np.array(g))
 print("if transpose(psum)=psum -> each dw_j = 2*sum(c) = 200")

@@ -2,12 +2,12 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import dataclasses
 import jax, jax.numpy as jnp, numpy as np
-from repro import compat
+from repro.launch.mesh import make_mesh
 from jax.sharding import PartitionSpec as P
 from repro.models import transformer as T
 from repro.models.common import Dist
 
-mesh = compat.make_mesh((2,4), ("data","model"))
+mesh = make_mesh((2,4), ("data","model"))
 cfg0 = T.TransformerConfig("a", n_layers=2, d_model=64, n_heads=8, n_kv_heads=2, head_dim=16,
     d_ff=128, vocab=256, qkv_bias=True, dtype=jnp.float32, param_dtype=jnp.float32, attn_chunk=8)
 cfg_sp = dataclasses.replace(cfg0, seq_parallel=True)
@@ -21,7 +21,7 @@ def tl(cfg):
     def f(p, t, l):
         loss, met = T.lm_loss(p, t, l, cfg, dist, 4)
         return jax.lax.pmean(met["ce"], ("data",))
-    return jax.jit(compat.shard_map(f, mesh=mesh, in_specs=(specs, P("data",None), P("data",None)),
+    return jax.jit(jax.shard_map(f, mesh=mesh, in_specs=(specs, P("data",None), P("data",None)),
                    out_specs=P(), check_vma=False))
 
 l0 = tl(cfg0)(pT, toks, labs)

@@ -1,14 +1,14 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
-from repro import compat
+from repro.launch.mesh import make_mesh
 from jax.sharding import PartitionSpec as P
 from repro.models.transformer import (TransformerConfig, init_params, lm_loss, prefill,
     decode_step, make_param_specs)
 from repro.models.moe import MoEConfig
 from repro.models.common import Dist
 
-mesh = compat.make_mesh((2,4), ("data","model"))
+mesh = make_mesh((2,4), ("data","model"))
 TP = 4
 
 def run_case(name, cfg):
@@ -34,7 +34,7 @@ def run_case(name, cfg):
     def tl(p, t, l):
         loss, met = lm_loss(p, t, l, cfg, dist, TP)
         return jax.lax.pmean(met["ce"], ("data",))
-    f = jax.jit(compat.shard_map(tl, mesh=mesh, in_specs=(specs, P("data",None), P("data",None)),
+    f = jax.jit(jax.shard_map(tl, mesh=mesh, in_specs=(specs, P("data",None), P("data",None)),
                               out_specs=P(), check_vma=False))
     lossT = f(pT, toks, labs)
     np.testing.assert_allclose(float(lossT), float(loss1), rtol=2e-5, atol=1e-5)
@@ -43,13 +43,13 @@ def run_case(name, cfg):
     def pf(p, t):
         return prefill(p, t, cfg, dist, TP, 32)
     cache_specs = {"k": P(None, "data", "model", None, None), "v": P(None, "data", "model", None, None)}
-    fpf = jax.jit(compat.shard_map(pf, mesh=mesh, in_specs=(specs, P("data",None)),
+    fpf = jax.jit(jax.shard_map(pf, mesh=mesh, in_specs=(specs, P("data",None)),
                   out_specs=(P("data"), cache_specs), check_vma=False))
     nxtT, cacheT = fpf(pT, toks)
     assert np.array_equal(np.array(nxtT), np.array(nxt1)), f"{name} prefill TP mismatch {nxtT} vs {nxt1}"
     def dc(p, t, c):
         return decode_step(p, t, c, jnp.int32(16), cfg, dist, TP)
-    fdc = jax.jit(compat.shard_map(dc, mesh=mesh, in_specs=(specs, P("data"), cache_specs),
+    fdc = jax.jit(jax.shard_map(dc, mesh=mesh, in_specs=(specs, P("data"), cache_specs),
                   out_specs=(P("data"), cache_specs), check_vma=False))
     nxtTb, _ = fdc(pT, nxtT, cacheT)
     assert np.array_equal(np.array(nxtTb), np.array(nxt1b)), f"{name} decode TP mismatch {nxtTb} vs {nxt1b}"

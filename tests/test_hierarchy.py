@@ -18,7 +18,7 @@ import pytest
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
+from repro.launch import mesh as meshlib
 from repro.core.config import HierarchyConfig
 from repro.core.hierarchy import (
     hierarchical_pmean,
@@ -33,7 +33,7 @@ from repro.core.topology import NetworkTopology
 def make_mesh():
     n = jax.device_count()
     pods = 2 if n % 2 == 0 else 1
-    return compat.make_mesh((pods, n // pods), ("pod", "data")), pods, n // pods
+    return meshlib.make_mesh((pods, n // pods), ("pod", "data")), pods, n // pods
 
 
 def sharded_rows(n, inner):
@@ -52,8 +52,9 @@ def test_hierarchical_psum_and_pmean_match_flat():
                 hierarchical_psum(xs, ("data",), "pod"),
                 hierarchical_pmean(xs, ("data",), "pod"))
 
-    g = jax.jit(compat.shard_map(f, mesh=mesh, in_specs=P(("pod", "data")),
-                                 out_specs=(P(None), P(None), P(None))))
+    g = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P(("pod", "data")),
+                                 out_specs=(P(None), P(None), P(None)),
+                                 check_vma=False))
     flat, hier, mean = g(x)
     assert hier.shape == flat.shape
     np.testing.assert_allclose(np.asarray(flat), np.asarray(hier), rtol=1e-6)
@@ -70,8 +71,9 @@ def test_hierarchical_psum_no_outer_axis_is_plain_psum():
                 hierarchical_psum(xs, ("data",), None),
                 hierarchical_pmean(xs, ("data",), None))
 
-    g = jax.jit(compat.shard_map(f, mesh=mesh, in_specs=P(("pod", "data")),
-                                 out_specs=(P("pod"), P("pod"), P("pod"))))
+    g = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P(("pod", "data")),
+                                 out_specs=(P("pod"), P("pod"), P("pod")),
+                                 check_vma=False))
     flat, hier, mean = g(x)
     np.testing.assert_array_equal(np.asarray(flat), np.asarray(hier))
     np.testing.assert_allclose(np.asarray(flat) / inner, np.asarray(mean),
@@ -87,8 +89,9 @@ def test_two_level_all_gather_matches_flat():
         return (lax.all_gather(xs, ("pod", "data"), axis=0, tiled=True),
                 two_level_all_gather(xs, ("data",), "pod", axis=0))
 
-    g = jax.jit(compat.shard_map(f, mesh=mesh, in_specs=P(("pod", "data")),
-                                 out_specs=(P(None), P(None))))
+    g = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P(("pod", "data")),
+                                 out_specs=(P(None), P(None)),
+                                 check_vma=False))
     flat, staged = g(x)
     # pure data movement: inner-then-outer staging is pod-major like the
     # flat multi-axis gather, and bytes are never touched arithmetically
@@ -103,8 +106,9 @@ def test_two_level_all_gather_no_outer_axis():
         return (lax.all_gather(xs, "data", axis=0, tiled=True),
                 two_level_all_gather(xs, ("data",), None, axis=0))
 
-    g = jax.jit(compat.shard_map(f, mesh=mesh, in_specs=P(("pod", "data")),
-                                 out_specs=(P("pod"), P("pod"))))
+    g = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P(("pod", "data")),
+                                 out_specs=(P("pod"), P("pod")),
+                                 check_vma=False))
     flat, staged = g(x)
     np.testing.assert_array_equal(np.asarray(flat), np.asarray(staged))
 
@@ -117,8 +121,9 @@ def test_hierarchical_psum_preserves_nd_shape():
     def f(xs):
         return hierarchical_psum(xs, ("data",), "pod")
 
-    g = jax.jit(compat.shard_map(f, mesh=mesh, in_specs=P(("pod", "data")),
-                                 out_specs=P(None)))
+    g = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P(("pod", "data")),
+                                 out_specs=P(None),
+                                 check_vma=False))
     out = g(x)
     assert out.shape == (2, inner * 3)  # per-device block shape survives
 

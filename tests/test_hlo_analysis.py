@@ -67,6 +67,22 @@ def test_scanned_params_bytes_not_multiplied():
     assert a["bytes"] < 3.5 * stack_bytes, a["bytes"] / stack_bytes
 
 
+def test_collectives_with_tpu_layouts_in_tuple_types():
+    """A TPU-compiled tuple-typed collective carries parentheses in its
+    layouts (``{0:T(1024)}``); it must still be counted."""
+    text = """HloModule m
+
+ENTRY %main (p0: f32[8]) -> (f32[8], f32[]) {
+  %p0 = f32[8]{0:T(1024)} parameter(0)
+  %c = f32[]{:T(128)} constant(1)
+  ROOT %all-reduce.1 = (f32[8]{0:T(1024)}, f32[]{:T(128)}) all-reduce(%p0, %c), channel_id=2, replica_groups={{0,1,2,3}}, to_apply=%add
+}
+"""
+    a = analyze_hlo(text)
+    assert a["collective_raw"] == {"all-reduce": 8 * 4 + 4}
+    assert a["collective_wire"] == {"all-reduce": 36 * 2 * 3 / 4}
+
+
 def test_collectives_inside_scan_multiplied():
     import os
     import subprocess
@@ -78,15 +94,15 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.launch.hlo_analysis import analyze_hlo
-mesh = compat.make_mesh((4,), ("model",))
+mesh = make_mesh((4,), ("model",))
 def body(x, _):
     return jax.lax.psum(x, "model"), None
 def f(x):
     y, _ = jax.lax.scan(body, x, None, length=7)
     return y
-g = jax.jit(compat.shard_map(f, mesh=mesh, in_specs=P(None), out_specs=P(None),
+g = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P(None), out_specs=P(None),
                              check_vma=False))
 txt = g.lower(jax.ShapeDtypeStruct((1024,), jnp.float32)).compile().as_text()
 a = analyze_hlo(txt)

@@ -122,7 +122,7 @@ def test_placement_rejects_unknown_policy_and_bad_shapes():
 # ---------------------------------------------------------------------------
 # jagged batch format
 # ---------------------------------------------------------------------------
-@settings(max_examples=25)
+@settings(max_examples=25, deadline=None)
 @given(nbags=st.integers(1, 8), max_len=st.integers(0, 6),
        seed=st.integers(0, 10_000))
 def test_jagged_to_padded_preserves_bags(nbags, max_len, seed):
@@ -173,7 +173,7 @@ def test_jagged_bad_offsets_rejected():
 # ---------------------------------------------------------------------------
 # kernel / lookup bit-identity
 # ---------------------------------------------------------------------------
-@settings(max_examples=15)
+@settings(max_examples=15, deadline=None)
 @given(b=st.integers(1, 6), length=st.integers(1, 5),
        seed=st.integers(0, 10_000))
 def test_embedding_bag_pallas_matches_ref_bit_exact(b, length, seed):
@@ -205,7 +205,7 @@ def test_embedding_bag_matches_slot_order_fold():
     np.testing.assert_allclose(np.asarray(out), fold, rtol=1e-6, atol=1e-6)
 
 
-@settings(max_examples=10)
+@settings(max_examples=10, deadline=None)
 @given(shards=st.sampled_from([1, 2, 8]),
        policy=st.sampled_from(["hash", "range"]),
        seed=st.integers(0, 10_000))
@@ -321,7 +321,7 @@ def test_sharded_training_bit_identical_to_single_table(shards, racks,
                                   sharded.row_versions("t0"))
 
 
-@settings(max_examples=8)
+@settings(max_examples=8, deadline=None)
 @given(shards=st.sampled_from([2, 8]),
        policy=st.sampled_from(["hash", "range"]),
        seed=st.integers(0, 10_000))
@@ -450,7 +450,7 @@ def test_replication_ships_only_delta_rows():
 # ---------------------------------------------------------------------------
 # hot-row serving
 # ---------------------------------------------------------------------------
-@settings(max_examples=6)
+@settings(max_examples=6, deadline=None)
 @given(skew=st.sampled_from([0.0, 0.8, 1.2]), seed=st.integers(0, 1000))
 def test_cached_reads_bit_identical_to_direct(skew, seed):
     """Headline serving invariant: under a Zipfian trace interleaved with
