@@ -30,7 +30,9 @@ strategies, matching the paper's comparison set:
 
 All strategies share identical update semantics (tested equal to the
 reference optimizer): they differ only in where bytes move — which is the
-paper's thesis.
+paper's thesis.  Each stage runs under a ``jax.named_scope`` (``push``,
+``apply``, ``pull``), so a profile of the compiled step names its device
+work by stage.
 """
 from __future__ import annotations
 
@@ -142,45 +144,51 @@ class PSExchange:
         nw = self._num_workers()
 
         if cfg.strategy == "allreduce":
-            g = lax.psum(gflat, self.worker_axes) / nw
-            new_p, new_slots = fused_aggregate_update(
-                g[None],
-                pflat,
-                state["slots"],
-                spec,
-                step,
-                lr_scale,
-                average=False,
-                use_pallas=cfg.use_pallas,
-            )
+            with jax.named_scope("push"):
+                g = lax.psum(gflat, self.worker_axes) / nw
+            with jax.named_scope("apply"):
+                new_p, new_slots = fused_aggregate_update(
+                    g[None],
+                    pflat,
+                    state["slots"],
+                    spec,
+                    step,
+                    lr_scale,
+                    average=False,
+                    use_pallas=cfg.use_pallas,
+                )
             return new_p, {"slots": new_slots, "ef": state["ef"], "step": step}
 
         if cfg.strategy == "pbox":
             # push: one reduce-scatter over all worker axes (aggregation on
             # the wire), arriving already summed at the chunk owner.
-            slab = lax.psum_scatter(
-                gflat, self.worker_axes, scatter_dimension=0, tiled=True
-            )
-            slab = slab / nw
-            widx = lax.axis_index(self.worker_axes)
-            n = slab.shape[0]
-            pslab = lax.dynamic_slice_in_dim(pflat, widx * n, n)
-            new_slab, new_slots = fused_aggregate_update(
-                slab[None],
-                pslab,
-                state["slots"],
-                spec,
-                step,
-                lr_scale,
-                average=False,
-                use_pallas=cfg.use_pallas,
-            )
+            with jax.named_scope("push"):
+                slab = lax.psum_scatter(
+                    gflat, self.worker_axes, scatter_dimension=0, tiled=True
+                )
+                slab = slab / nw
+            with jax.named_scope("apply"):
+                widx = lax.axis_index(self.worker_axes)
+                n = slab.shape[0]
+                pslab = lax.dynamic_slice_in_dim(pflat, widx * n, n)
+                new_slab, new_slots = fused_aggregate_update(
+                    slab[None],
+                    pslab,
+                    state["slots"],
+                    spec,
+                    step,
+                    lr_scale,
+                    average=False,
+                    use_pallas=cfg.use_pallas,
+                )
             # pull: one all-gather of updated slabs
-            pulled = new_slab
-            if cfg.pull_dtype is not None:
-                pulled = pulled.astype(cfg.pull_dtype)
-            new_p = lax.all_gather(pulled, self.worker_axes, axis=0, tiled=True)
-            new_p = new_p.astype(pflat.dtype)
+            with jax.named_scope("pull"):
+                pulled = new_slab
+                if cfg.pull_dtype is not None:
+                    pulled = pulled.astype(cfg.pull_dtype)
+                new_p = lax.all_gather(pulled, self.worker_axes, axis=0,
+                                       tiled=True)
+                new_p = new_p.astype(pflat.dtype)
             return new_p, {"slots": new_slots, "ef": state["ef"], "step": step}
 
         if cfg.strategy == "pbox_hier":
@@ -190,47 +198,53 @@ class PSExchange:
             for a in data_axes:
                 n_data *= lax.axis_size(a)
             n_pod = lax.axis_size(pod)
-            # stage 1: rack-local aggregation (reduce-scatter within pod)
-            slab = lax.psum_scatter(
-                gflat, data_axes, scatter_dimension=0, tiled=True
-            )
-            slab = slab / nw
-            # stage 2: single aggregated stream across pods, optionally int8
-            ef = state["ef"]
-            if cfg.compression.codec == "none":
-                slab = lax.psum(slab, pod)
-            else:
-                payload, ef = comp.encode(cfg.compression, slab, ef)
-                # integer aggregation across pods: gather peers' compressed
-                # payloads, decode, and sum locally (models switch-side
-                # integer adds with per-chunk rescale).
-                gathered = tuple(
-                    lax.all_gather(p, pod, axis=0, tiled=False) for p in payload
+            with jax.named_scope("push"):
+                # stage 1: rack-local aggregation (reduce-scatter within pod)
+                slab = lax.psum_scatter(
+                    gflat, data_axes, scatter_dimension=0, tiled=True
                 )
-                parts = [
-                    comp.decode(cfg.compression, tuple(g[i] for g in gathered))
-                    for i in range(n_pod)
-                ]
-                slab = jnp.sum(jnp.stack(parts), axis=0)
-            widx = lax.axis_index(data_axes)
-            n = slab.shape[0]
-            pslab = lax.dynamic_slice_in_dim(pflat, widx * n, n)
-            new_slab, new_slots = fused_aggregate_update(
-                slab[None],
-                pslab,
-                state["slots"],
-                spec,
-                step,
-                lr_scale,
-                average=False,
-                use_pallas=cfg.use_pallas,
-            )
+                slab = slab / nw
+                # stage 2: single aggregated stream across pods, optionally
+                # int8
+                ef = state["ef"]
+                if cfg.compression.codec == "none":
+                    slab = lax.psum(slab, pod)
+                else:
+                    payload, ef = comp.encode(cfg.compression, slab, ef)
+                    # integer aggregation across pods: gather peers'
+                    # compressed payloads, decode, and sum locally (models
+                    # switch-side integer adds with per-chunk rescale).
+                    gathered = tuple(
+                        lax.all_gather(p, pod, axis=0, tiled=False)
+                        for p in payload
+                    )
+                    parts = [
+                        comp.decode(cfg.compression,
+                                    tuple(g[i] for g in gathered))
+                        for i in range(n_pod)
+                    ]
+                    slab = jnp.sum(jnp.stack(parts), axis=0)
+            with jax.named_scope("apply"):
+                widx = lax.axis_index(data_axes)
+                n = slab.shape[0]
+                pslab = lax.dynamic_slice_in_dim(pflat, widx * n, n)
+                new_slab, new_slots = fused_aggregate_update(
+                    slab[None],
+                    pslab,
+                    state["slots"],
+                    spec,
+                    step,
+                    lr_scale,
+                    average=False,
+                    use_pallas=cfg.use_pallas,
+                )
             # pull stays inside the pod: updates are replicated across pods
-            pulled = new_slab
-            if cfg.pull_dtype is not None:
-                pulled = pulled.astype(cfg.pull_dtype)
-            new_p = lax.all_gather(pulled, data_axes, axis=0, tiled=True)
-            new_p = new_p.astype(pflat.dtype)
+            with jax.named_scope("pull"):
+                pulled = new_slab
+                if cfg.pull_dtype is not None:
+                    pulled = pulled.astype(cfg.pull_dtype)
+                new_p = lax.all_gather(pulled, data_axes, axis=0, tiled=True)
+                new_p = new_p.astype(pflat.dtype)
             return new_p, {"slots": new_slots, "ef": ef, "step": step}
 
         raise ValueError(cfg.strategy)

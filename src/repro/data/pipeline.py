@@ -2,7 +2,9 @@
 
 A background thread keeps ``depth`` batches materialized ahead of the
 training loop (the host-side half of compute/transfer overlap; on real TPU
-hosts this hides input latency behind the device step)."""
+hosts this hides input latency behind the device step). Each fill (the
+upstream ``next`` and the ``transform``) is recorded as an ``input_fill``
+span in a profiler trace."""
 from __future__ import annotations
 
 import queue
@@ -10,6 +12,9 @@ import threading
 from typing import Callable, Iterator
 
 import jax
+from jax.profiler import TraceAnnotation
+
+_END = object()  # the upstream iterator is exhausted
 
 
 class Prefetcher:
@@ -25,10 +30,14 @@ class Prefetcher:
 
     def _work(self):
         try:
-            for item in self.it:
-                if self._stop.is_set():
-                    return
-                self.q.put(self.transform(item))
+            it = iter(self.it)
+            while True:
+                with TraceAnnotation("input_fill"):
+                    item = next(it, _END)
+                    if item is _END or self._stop.is_set():
+                        return
+                    item = self.transform(item)
+                self.q.put(item)
         except BaseException as e:  # noqa: BLE001
             self._err = e
             self.q.put(None)

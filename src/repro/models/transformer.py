@@ -427,13 +427,15 @@ def _layer(x, lp, layer_idx, cfg: TransformerConfig, dist: Dist, tp: int, positi
         return dist.psum_scatter_model(y, axis=1) if sp else dist.psum_model(y)
 
     h = block_in(x)
-    a_out = _attn_block(h, lp, cfg, dist, tp, is_global, positions,
-                        combine=block_out)
+    with jax.named_scope("attn"):
+        a_out = _attn_block(h, lp, cfg, dist, tp, is_global, positions,
+                            combine=block_out)
     x = x + a_out
     h = rms_norm(x, lp["ln2"], cfg.eps)
     if sp:
         h = dist.all_gather_model(h, axis=1)
-    f, aux = _ffn_block(h, lp, cfg, dist, combine=block_out)
+    with jax.named_scope("mlp"):
+        f, aux = _ffn_block(h, lp, cfg, dist, combine=block_out)
     return x + f, aux
 
 
@@ -461,29 +463,31 @@ def lm_loss(params, tokens, labels, cfg: TransformerConfig, dist: Dist, tp: int)
     """Distributed-softmax CE over the vocab-sharded head. Returns scalar
     per-worker mean loss (caller pmeans over workers)."""
     x, aux = forward(params, tokens, cfg, dist, tp)
-    x = rms_norm(x, params["ln_f"], cfg.eps)
-    if cfg.seq_parallel and dist.model_axis is not None:
-        # re-assemble the full sequence for the vocab-sharded head
-        x = dist.all_gather_model(x, axis=1)
-    head = params["head"]  # (Vloc, d)
-    vloc = head.shape[0]
-    logits = (x @ head.T).astype(jnp.float32)  # (B, S, Vloc)
-    midx = dist.model_index()
-    # mask vocab-padding rows out of the softmax
-    gid = midx * vloc + jnp.arange(vloc)
-    logits = jnp.where(gid < cfg.vocab, logits, -1e30)
-    local = labels - midx * vloc
-    ok = (local >= 0) & (local < vloc)
-    lab = jnp.clip(local, 0, vloc - 1)
-    lab_logit = jnp.take_along_axis(logits, lab[..., None], axis=-1)[..., 0]
-    lab_logit = dist.psum_model(jnp.where(ok, lab_logit, 0.0))
-    # stability max is gradient-free (exact: d lse/d logits is softmax);
-    # stop_gradient *before* pmax — pmax has no differentiation rule
-    mx = dist.pmax_model(jnp.max(lax.stop_gradient(logits), axis=-1))
-    lse = mx + jnp.log(
-        dist.psum_model(jnp.sum(jnp.exp(logits - mx[..., None]), axis=-1))
-    )
-    ce = jnp.mean(lse - lab_logit)
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x, params["ln_f"], cfg.eps)
+        if cfg.seq_parallel and dist.model_axis is not None:
+            # re-assemble the full sequence for the vocab-sharded head
+            x = dist.all_gather_model(x, axis=1)
+        head = params["head"]  # (Vloc, d)
+        vloc = head.shape[0]
+        logits = (x @ head.T).astype(jnp.float32)  # (B, S, Vloc)
+        midx = dist.model_index()
+        # mask vocab-padding rows out of the softmax
+        gid = midx * vloc + jnp.arange(vloc)
+        logits = jnp.where(gid < cfg.vocab, logits, -1e30)
+        local = labels - midx * vloc
+        ok = (local >= 0) & (local < vloc)
+        lab = jnp.clip(local, 0, vloc - 1)
+        lab_logit = jnp.take_along_axis(logits, lab[..., None],
+                                        axis=-1)[..., 0]
+        lab_logit = dist.psum_model(jnp.where(ok, lab_logit, 0.0))
+        # stability max is gradient-free (exact: d lse/d logits is softmax);
+        # stop_gradient *before* pmax — pmax has no differentiation rule
+        mx = dist.pmax_model(jnp.max(lax.stop_gradient(logits), axis=-1))
+        lse = mx + jnp.log(
+            dist.psum_model(jnp.sum(jnp.exp(logits - mx[..., None]), axis=-1))
+        )
+        ce = jnp.mean(lse - lab_logit)
     return ce + aux, {"ce": ce, "aux": aux}
 
 

@@ -217,15 +217,19 @@ def make_ps_train_step(
     lr_schedule: Callable | None = None,
     donate: bool = True,
     microbatches: int = 1,
-    telemetry: ServerStats | None = None,
 ):
     """Returns (jitted step, ParamSpace, state_specs, n_groups).
 
     step(pflat, slots, ef, step_count, batch) ->
         (new_pflat, new_slots, new_ef, new_step, metrics)
 
-    If ``telemetry`` is given, the returned step is wrapped with
-    ``attach_telemetry`` so each call records modeled wire bytes there.
+    The step's work carries stable ``jax.named_scope`` names: ``fwd`` around
+    the loss (so the backward reads ``transpose(jvp(fwd))`` and a
+    recomputed forward ``rematted_computation``), ``accumulate`` around the
+    gradient's flatten and microbatch sum, the exchange's ``push``,
+    ``apply`` and ``pull``, and ``pull`` as well around the pulled
+    parameters' layout: their reshape into the state's row and, at the next
+    step's start, their unflatten into the model's tree.
     """
     tp = dist.tp if dist.model_axis is not None else 1
     n_groups = tp if dist.model_axis is not None else 1
@@ -239,14 +243,17 @@ def make_ps_train_step(
     sspecs = _state_specs(exchange, n_state, has_ef)
 
     def device_step(pflat, slots, ef, step_cnt, batch):
-        pf = pflat.reshape(-1)  # (flat_local,)
+        # the pulled parameters, from the state's row into the model's tree
+        with jax.named_scope("pull"):
+            pf = pflat.reshape(-1)  # (flat_local,)
+            params = space.unflatten(pf)
         slots_l = tuple(s.reshape(-1) for s in slots)
         ef_l = ef.reshape(-1) if ef is not None else None
-        params = space.unflatten(pf)
 
         def grads_of(mb):
             def lf_tree(params_):
-                loss, met = loss_fn(params_, mb, dist)
+                with jax.named_scope("fwd"):
+                    loss, met = loss_fn(params_, mb, dist)
                 lossd = loss / tp if (loss_div_tp and tp > 1) else loss
                 return lossd, (loss, met)
 
@@ -254,7 +261,9 @@ def make_ps_train_step(
                 params
             )
             grads = apply_grad_sync(grads, sync_tags, dist)
-            return space.flatten(grads, ps_dtype), loss, met
+            with jax.named_scope("accumulate"):
+                gflat = space.flatten(grads, ps_dtype)
+            return gflat, loss, met
 
         if microbatches <= 1:
             gflat, loss, met = grads_of(batch)
@@ -268,12 +277,15 @@ def make_ps_train_step(
 
             def body(acc, mb):
                 g, loss, met = grads_of(mb)
-                return acc + g, (loss, met)
+                with jax.named_scope("accumulate"):
+                    acc = acc + g
+                return acc, (loss, met)
 
             gflat, (losses, mets) = lax.scan(
                 body, jnp.zeros((space.flat_elems,), ps_dtype), mbs
             )
-            gflat = gflat / microbatches
+            with jax.named_scope("accumulate"):
+                gflat = gflat / microbatches
             loss = jnp.mean(losses)
             met = jax.tree.map(jnp.mean, mets)
 
@@ -289,8 +301,10 @@ def make_ps_train_step(
         new_ef = (
             new_state["ef"].reshape(1, -1) if new_state["ef"] is not None else None
         )
+        with jax.named_scope("pull"):
+            new_pf = new_pf.reshape(1, -1)
         return (
-            new_pf.reshape(1, -1),
+            new_pf,
             new_slots,
             new_ef,
             new_state["step"],
@@ -317,8 +331,6 @@ def make_ps_train_step(
     )
     jit_kwargs = {"donate_argnums": (0, 1, 2)} if donate else {}
     step = jax.jit(shmap, **jit_kwargs)
-    if telemetry is not None:
-        step = attach_telemetry(step, exchange, space, mesh, telemetry)
     return step, space, sspecs, n_groups
 
 

@@ -7,6 +7,7 @@ accumulation, the path ``chip_smoke.py`` drives at full width on a TPU.
 result.  The compile cache goes where ``JAX_COMPILATION_CACHE_DIR`` says,
 and only there.
 """
+import json
 import math
 import os
 import subprocess
@@ -87,6 +88,42 @@ def test_compile_cache_writes_only_where_the_environment_says(tmp_path):
     assert any(target.iterdir())
     after = set(default.rglob("*")) if default.exists() else set()
     assert after == before
+
+
+def test_compile_cache_keeps_apart_steps_whose_scopes_differ(tmp_path):
+    """An entry serves only a program with the same scopes (a profile reads
+    the phases from the executable's metadata), and a checkout moved to
+    another path still finds its own entries."""
+    code = ("import json, os, sys, jax, jax.numpy as jnp\n"
+            "from repro.launch.compile_cache import enable_compile_cache\n"
+            "enable_compile_cache()\n"
+            "jax.config.update('jax_persistent_cache_min_compile_time_secs', 0)\n"
+            "def f(x):\n"
+            "    with jax.named_scope(sys.argv[1]):\n"
+            "        return jnp.sin(x) * 2\n"
+            "text = jax.jit(f).lower(jnp.ones(8)).compile().as_text()\n"
+            "print(sys.argv[1] in text, json.dumps(sorted(n for n in os.listdir(\n"
+            "    os.environ['JAX_COMPILATION_CACHE_DIR']) if n.startswith('jit_f-'))))\n")
+    for tree in ("a", "b"):  # two copies of a checkout at two paths
+        launch = tmp_path / tree / "src" / "repro" / "launch"
+        launch.mkdir(parents=True)
+        (launch / "__init__.py").write_text("")
+        (launch / "compile_cache.py").write_text(
+            (REPO / "src/repro/launch/compile_cache.py").read_text())
+        (tmp_path / tree / "step.py").write_text(code)
+    cache = {"JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cache")}
+    seen = []
+    for tree, scope in (("a", "fwd"), ("b", "fwd"), ("a", "bwd")):
+        root = tmp_path / tree
+        p = _run([str(root / "step.py"), scope],
+                 {**cache, "PYTHONPATH": str(root / "src")}, cwd=root)
+        assert p.returncode == 0, p.stderr
+        named, entries = p.stdout.strip().split(" ", 1)
+        assert named == "True"
+        seen.append(json.loads(entries))
+    assert len(seen[0]) == 1
+    assert seen[1] == seen[0]  # the moved checkout hits the same entry
+    assert len(seen[2]) == 2  # other scopes: an entry of their own
 
 
 def test_chip_smoke_refuses_a_cpu_host():
