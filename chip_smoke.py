@@ -15,7 +15,8 @@ training path, then every Pallas kernel compiled for the chip.
    ``fused_agg_opt`` kernel (``use_pallas=True``); its parameters must match
    phase 2's within ``PARAM_TOL``.
 4. Every kernel with ``interpret=False`` at the sizes of
-   ``tests/test_tpu_compile.py``, checked against its ``ref.py``.
+   ``tests/test_tpu_compile.py``, checked against its ``ref.py`` (causal
+   attention: its output and the gradients of q, k and v).
 5. (``--chips 4``) phases 2 and 3 on a 4x1 mesh with strategy ``pbox``,
    checked after step 1 against strategy ``allreduce`` on the same seed and
    batches, with the collectives of each compiled step; at matmul precision
@@ -56,6 +57,8 @@ STREAMS = 4
 CHUNK = 8192
 CODEC_ELEMS = CHUNK * 64
 TABLE_ROWS, EMB_DIM, BAGS, BAG_LEN = 100_000, 128, 256, 8
+SEQ, HEADS, HEAD_DIM = 4096, 16, 128  # the LM cell's attention, one sequence
+ATTN_TOL = 1e-2  # relative (Frobenius) error of bf16 outputs and gradients
 
 
 def device_info() -> dict:
@@ -209,6 +212,38 @@ def kernels() -> None:
     _check("embedding_bag",
            embedding_bag(table, idx, w, "sum", use_pallas=True, interpret=False),
            want, rtol=1e-5, atol=1e-5)
+
+    attention()
+
+
+def attention() -> None:
+    """The causal attention kernel compiled for the chip against its
+    ``ref.py`` at the LM cell's widths: the output and the gradients of q,
+    k and v."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.kernels.attention.ops import causal_attention
+    from repro.kernels.attention.ref import causal_attention_ref
+
+    ks = iter(jax.random.split(jax.random.PRNGKey(1), 4))
+    qkv = [jax.random.normal(next(ks), (1, SEQ, HEADS, HEAD_DIM), jnp.bfloat16)
+           for _ in range(3)]
+    do = jax.random.normal(next(ks), (1, SEQ, HEADS, HEAD_DIM), jnp.bfloat16)
+
+    def out_and_grads(fn):
+        out, pull = jax.vjp(fn, *qkv)
+        return (out, *pull(do))
+
+    got = out_and_grads(lambda q, k, v: causal_attention(q, k, v, interpret=False))
+    for name, g, w in zip(("out", "dq", "dk", "dv"), got,
+                          out_and_grads(causal_attention_ref)):
+        g, w = (np.asarray(x, np.float64) for x in (g, w))
+        err = float(np.linalg.norm(g - w) / np.linalg.norm(w))
+        print(f"  attention/{name} shape={g.shape} relative error={err!r}",
+              flush=True)
+        if not err < ATTN_TOL:
+            raise AssertionError(f"attention/{name}: {err} against ref.py")
 
 
 def main(argv=None) -> None:

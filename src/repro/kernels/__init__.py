@@ -1,6 +1,6 @@
 """The Pallas kernel tier — see docs/kernels.md for the full map.
 
-Four families, each a ``ref.py`` (pure-jnp oracle) / ``kernel.py`` (Pallas
+Five families, each a ``ref.py`` (pure-jnp oracle) / ``kernel.py`` (Pallas
 program) / ``ops.py`` (validated, jit'd public surface) package:
 
 * ``fused_agg_opt`` — K-way gradient aggregation fused with the server
@@ -9,7 +9,9 @@ program) / ``ops.py`` (validated, jit'd public surface) package:
 * ``embedding_bag`` — scalar-prefetch embedding gather/reduce for the
   sparse tier;
 * ``wire_path`` — single-pass decode + aggregate + optimize over wire-form
-  push payloads, bit-identical to the unfused pipeline.
+  push payloads, bit-identical to the unfused pipeline;
+* ``attention`` — causal flash attention for the transformer's training
+  step, built on the flash-attention kernels shipped with JAX.
 
 Import from each family's package (``repro.kernels.<family>``); this
 package re-exports nothing.  It holds the one rule for how every kernel

@@ -32,6 +32,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from repro.kernels import interpret_mode
+from repro.kernels.attention import causal_attention, kernel_fits
 from repro.models.common import (
     Dist,
     apply_rope,
@@ -372,6 +374,23 @@ def _chunked_attention(q, k, v, cfg: TransformerConfig, is_global, q0: int = 0):
     return out
 
 
+def _attention(q, k, v, cfg: TransformerConfig, is_global, q0: int = 0):
+    """Causal attention of q over k/v, (B, S, H, hd) each.
+
+    On a TPU, a global causal layer over a whole sequence from position 0
+    whose length and head size the flash kernel tiles
+    (``kernels.attention.kernel_fits``) runs the kernel: its scores stay in
+    VMEM and the blocks above the diagonal are skipped. Everything else
+    (windowed layers, ``q0 > 0``, shapes that do not tile, other backends)
+    takes ``_chunked_attention``."""
+    sq, hd = q.shape[1], q.shape[3]
+    if (not interpret_mode() and cfg.sliding_window is None and q0 == 0
+            and k.shape[1] == sq and kernel_fits(sq, hd)):
+        with jax.named_scope("attn_kernel"):
+            return causal_attention(q, k, v)
+    return _chunked_attention(q, k, v, cfg, is_global, q0)
+
+
 def _attn_block(x, lp, cfg: TransformerConfig, dist: Dist, tp: int, is_global,
                 positions, combine=None):
     b, s, _ = x.shape
@@ -379,7 +398,7 @@ def _attn_block(x, lp, cfg: TransformerConfig, dist: Dist, tp: int, is_global,
     combine = combine or dist.psum_model
     q, k, v = _qkv(x, lp, cfg, dist, positions)
     k, v = _kv_for_local_q(k, v, cfg, dist, tp)
-    out = _chunked_attention(q, k, v, cfg, is_global)
+    out = _attention(q, k, v, cfg, is_global)
     out = out.reshape(b, s, -1) @ lp["wo"]
     out = combine(out)
     if R > 1:
@@ -537,7 +556,7 @@ def prefill(params, tokens, cfg: TransformerConfig, dist: Dist, tp: int, max_seq
         kc = lax.dynamic_slice_in_dim(jnp.pad(kf, pad), midx * sloc, sloc, axis=1)
         vc = lax.dynamic_slice_in_dim(jnp.pad(vf, pad), midx * sloc, sloc, axis=1)
         ku, vu = _kv_for_local_q(k, v, cfg, dist, tp)
-        out = _chunked_attention(q, ku, vu, cfg, is_global)
+        out = _attention(q, ku, vu, cfg, is_global)
         out = out.reshape(x.shape[0], s, -1) @ lp["wo"]
         out = dist.psum_model(out)
         R = cfg.attn_replicas(tp)

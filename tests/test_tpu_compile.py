@@ -5,7 +5,8 @@ The TPU compiler is installed on CPU hosts too and compiles for a described
 aligned to the (8, 128) tile, too much VMEM.  Each case compiles the kernel
 with ``interpret=False`` through its public wrapper and checks that the
 compiled program holds the kernel (``tpu_custom_call``).  The sizes are
-the ones ``chip_smoke.py`` runs on the chip.
+the ones ``chip_smoke.py`` runs on the chip; attention's are the LM
+benchmark cell's.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and the fixture runs only in the worker
@@ -16,6 +17,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels.attention.ops import causal_attention
 from repro.kernels.embedding_bag.ops import embedding_bag
 from repro.kernels.fused_agg_opt.ops import fused_aggregate_update
 from repro.kernels.quant.ops import dequantize_chunks, quantize_chunks
@@ -27,6 +29,7 @@ STREAMS = 4
 CHUNK = 8192
 CODEC_ELEMS = CHUNK * 64
 TABLE_ROWS, EMB_DIM, BAGS, BAG_LEN = 100_000, 128, 256, 8
+SEQ, HEADS, HEAD_DIM = 4096, 16, 128  # the LM cell's attention, one sequence
 
 
 @pytest.fixture(scope="module")
@@ -105,3 +108,16 @@ def test_embedding_bag_compiles_for_v5e(sds):
                                       interpret=False),
         sds((TABLE_ROWS, EMB_DIM), jnp.float32),
         sds((BAGS, BAG_LEN), jnp.int32), sds((BAGS, BAG_LEN), jnp.float32))
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+def test_causal_attention_compiles_for_v5e(sds, grad):
+    def fwd(q, k, v):
+        return causal_attention(q, k, v, interpret=False)
+
+    def loss(q, k, v):
+        return jnp.sum(fwd(q, k, v).astype(jnp.float32))
+
+    qkv = [sds((1, SEQ, HEADS, HEAD_DIM), jnp.bfloat16) for _ in range(3)]
+    _assert_kernel_compiles(
+        jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd, *qkv)
