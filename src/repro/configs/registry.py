@@ -1,4 +1,5 @@
-"""Architecture registry: the 10 assigned archs (+ the paper's ResNet-50).
+"""Architecture registry: the 10 assigned archs, Moonlight-16B-A3B (the
+DeepSeek-V3 block) and the paper's ResNet-50.
 
 Each arch file exposes ``ARCH: ArchDef``; the registry imports them all and
 serves (arch × shape) cells to the launcher, dry-run and benchmarks.
@@ -44,6 +45,7 @@ def _build() -> dict:
         gemma3_1b,
         granite_moe_1b,
         internlm2_1_8b,
+        moonlight_16b_a3b,
         qwen2_72b,
         qwen2_moe_a2_7b,
         resnet50,
@@ -53,6 +55,7 @@ def _build() -> dict:
     mods = [
         gemma3_1b, internlm2_1_8b, qwen2_72b, granite_moe_1b, qwen2_moe_a2_7b,
         equiformer_v2, dlrm_mlperf, autoint, dien, xdeepfm, resnet50,
+        moonlight_16b_a3b,
     ]
     return {m.ARCH.arch_id: m.ARCH for m in mods}
 

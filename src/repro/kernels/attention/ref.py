@@ -1,7 +1,7 @@
 """Pure-jnp oracle for causal self-attention.
 
 The plain statement of what the kernel computes: scores from the inputs'
-dtype accumulated in f32, the ``1/sqrt(head_dim)`` scale and the softmax in
+dtype accumulated in f32, the ``1/sqrt(Dqk)`` scale and the softmax in
 f32, the probabilities rounded to the inputs' dtype for the product with
 ``v``.  The whole ``S x S`` score matrix is materialized, which is what the
 kernel exists to avoid.
@@ -15,11 +15,12 @@ import jax.numpy as jnp
 
 
 def causal_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
-    """Causal attention of ``q`` (B, S, H, D) over ``k``/``v`` (B, S, Hkv, D).
+    """Causal attention of ``q`` (B, S, H, Dqk) over ``k`` (B, S, Hkv, Dqk)
+    and ``v`` (B, S, Hkv, Dv).
 
     Query head ``h`` reads key/value head ``h // (H // Hkv)`` (grouped-query
     attention); position ``i`` attends to positions ``0..i``.  Returns
-    (B, S, H, D) in ``q``'s dtype."""
+    (B, S, H, Dv) in ``q``'s dtype."""
     s, h, d = q.shape[1:]
     group = h // k.shape[2]
     k = jnp.repeat(k, group, axis=2)

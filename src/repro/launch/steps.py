@@ -82,6 +82,12 @@ def _lm_dist(mesh) -> Dist:
                 tp=mesh.shape["model"])
 
 
+def _training_only(cfg: T.TransformerConfig):
+    if cfg.deepseek_block:
+        raise ValueError(f"{cfg.name}: the DeepSeek-V3 block (MLA, held "
+                         f"experts) has training cells only")
+
+
 def build_lm_train(arch: ArchDef, cell: ShapeCell, mesh,
                    exchange: PSExchange, smoke: bool = False,
                    variant: str | None = None) -> CellPlan:
@@ -138,6 +144,7 @@ def build_lm_train(arch: ArchDef, cell: ShapeCell, mesh,
 def build_lm_prefill(arch: ArchDef, cell: ShapeCell, mesh,
                      smoke: bool = False) -> CellPlan:
     cfg = arch.smoke_config if smoke else arch.config
+    _training_only(cfg)
     tp = mesh.shape["model"]
     dist = _lm_dist(mesh)
     wa = meshlib.worker_axes(mesh)
@@ -167,6 +174,7 @@ def build_lm_prefill(arch: ArchDef, cell: ShapeCell, mesh,
 def build_lm_decode(arch: ArchDef, cell: ShapeCell, mesh,
                     smoke: bool = False) -> CellPlan:
     cfg = arch.smoke_config if smoke else arch.config
+    _training_only(cfg)
     tp = mesh.shape["model"]
     dist = _lm_dist(mesh)
     wa = meshlib.worker_axes(mesh)
@@ -206,6 +214,7 @@ def build_lm_decode_long(arch: ArchDef, cell: ShapeCell, mesh,
                          smoke: bool = False) -> CellPlan:
     """Unrolled decode with per-layer cache sizes (sliding-window archs)."""
     cfg = arch.smoke_config if smoke else arch.config
+    _training_only(cfg)
     tp = mesh.shape["model"]
     dist = _lm_dist(mesh)
     gb, s = cell.params["global_batch"], cell.params["seq_len"]

@@ -119,6 +119,8 @@ def main(argv=None, exchange_cfg: ExchangeConfig | None = None,
         "lm": "train_4k", "recsys": "train_batch", "gnn": "molecule",
         "vision": "imagenet_train",
     }[arch.family]
+    if args.shape is None and shape not in (c.name for c in arch.cells):
+        shape = next(c.name for c in arch.cells if c.kind == "train")
     if exchange_cfg is None:
         exchange_cfg = ExchangeConfig(strategy=args.strategy)
     plan = build_cell(args.arch, shape, mesh, exchange_cfg=exchange_cfg,

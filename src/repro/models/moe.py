@@ -1,4 +1,20 @@
-"""Mixture-of-Experts FFN with capacity-based argsort dispatch.
+"""Mixture-of-Experts FFN: two expert layers.
+
+**Held experts, dropless** (``MoEConfig.experts_held`` set; the DeepSeek-V3
+block).  The layer is told which experts this chip holds (``first``,
+``count``).  It routes every token over all ``n_experts`` (sigmoid or
+softmax scores, a selection bias that picks experts but does not weight
+them, normalized top-k weights times ``routed_scale``), computes only the
+held experts' part of the result, and drops no assignment whatever the
+imbalance: the assignments are sorted by expert, the held ones first, and a
+grouped matmul over the held experts (``gmm``, JAX's megablox kernel) does
+work that follows the rows routed to them.  Its buffers are static and
+sized for the worst case, every assignment held.  What the experts held on
+other chips would add is left out; the shared experts are computed on every
+chip.  :func:`moe_ffn_held`.
+
+**Capacity, tensor-parallel** (``experts_held`` unset; granite and
+qwen2-moe), :func:`moe_ffn`:
 
 Experts are *tensor-parallel* over the ``model`` axis (every device holds a
 1/tp slice of every expert's hidden dim): routing and dispatch are computed
@@ -20,7 +36,20 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+
+from repro.kernels import interpret_mode
 from repro.models.common import Dist, act_fn
+
+GMM_ROWS = 512  # row tile of the grouped matmul
+
+
+def _tile(dim: int) -> int:
+    """The grouped matmul's tile of a contracted or output dimension: the
+    largest of 512, 256 and 128 that divides it (on a v5e at d 2048, f 1408
+    this ran the SwiGLU's forward and backward 3.9x faster than tiles of
+    128; PERF.md)."""
+    return next((t for t in (512, 256, 128) if dim % t == 0), min(128, dim))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -32,6 +61,14 @@ class MoEConfig:
     capacity_factor: float = 1.25
     aux_loss_coef: float = 0.01
     router_dtype: str = "float32"
+    # the held-experts path (all of these are read only where experts_held
+    # is set; the capacity path keeps softmax, normalized top-k, scale 1)
+    score_func: str = "softmax"  # "softmax" | "sigmoid"
+    norm_topk: bool = True  # renormalize the selected experts' scores to 1
+    routed_scale: float = 1.0  # times the routed experts' weights
+    seq_aux_coef: float = 0.0  # alpha of the sequence-wise balance loss
+    experts_held: tuple[int, int] | None = None  # (first, count) on this chip
+    select_bias: tuple[float, ...] | None = None  # b: picks, never weights
 
     def capacity(self, tokens: int) -> int:
         c = int(self.capacity_factor * tokens * self.top_k / self.n_experts)
@@ -111,3 +148,112 @@ def moe_ffn(
         hs = a(x @ weights["ws1"]) * (x @ weights["ws3"])
         out = out + hs @ weights["ws2"]
     return out.astype(x.dtype), aux
+
+
+# ---------------------------------------------------------------------------
+# held experts, dropless (DeepSeek-V3)
+# ---------------------------------------------------------------------------
+
+def route_held(logits: jax.Array, cfg: MoEConfig, seq_len: int):
+    """logits (T, E) f32 over all experts -> (weights (T, k), experts (T, k),
+    sequence-wise balance loss).
+
+    Scores ``s`` are ``sigmoid`` (or ``softmax``) of the logits; the experts
+    are the top-k of ``s + b`` (``cfg.select_bias``, zero where unset); the
+    weights are ``s`` at the selected experts, normalized to sum 1 where
+    ``cfg.norm_topk``, times ``cfg.routed_scale``.  The balance loss
+    (DeepSeek-V3, arXiv:2412.19437, eqs. 17-20) is, per sequence of
+    ``seq_len`` tokens, ``alpha * sum_i f_i * P_i`` with ``f_i`` the share
+    of the sequence's selections that went to expert ``i`` times ``E / k``
+    and ``P_i`` the mean over the sequence of ``s_i / sum_j s_j``; it is the
+    mean over sequences."""
+    t, e = logits.shape
+    k = cfg.top_k
+    if cfg.score_func == "sigmoid":
+        s = jax.nn.sigmoid(logits)
+    elif cfg.score_func == "softmax":
+        s = jax.nn.softmax(logits, axis=-1)
+    else:
+        raise ValueError(f"unknown score_func {cfg.score_func!r}")
+    pick = s if cfg.select_bias is None else s + jnp.asarray(
+        cfg.select_bias, jnp.float32)
+    _, idx = jax.lax.top_k(pick, k)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    if cfg.norm_topk:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    w = w * cfg.routed_scale
+    if cfg.seq_aux_coef:
+        nseq = t // seq_len
+        chosen = jnp.sum(jax.nn.one_hot(idx, e, dtype=jnp.float32), axis=1)
+        f = jnp.mean(chosen.reshape(nseq, seq_len, e), axis=1) * (e / k)
+        sn = s / jnp.sum(s, axis=-1, keepdims=True)
+        p = jnp.mean(sn.reshape(nseq, seq_len, e), axis=1)
+        aux = cfg.seq_aux_coef * jnp.mean(jnp.sum(f * p, axis=-1))
+    else:
+        aux = jnp.float32(0.0)
+    return w, idx, aux
+
+
+def gmm(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
+        interpret: bool | None = None) -> jax.Array:
+    """Grouped matmul: rows ``[o_g, o_g + n_g)`` of ``lhs`` (M, K) times
+    ``rhs[g]`` (G, K, N) for the first G of ``group_sizes``; rows of a last,
+    extra group come out zero and cost nothing.  Output in ``lhs``'s dtype,
+    accumulated in f32.  JAX's megablox kernel, differentiable in both
+    operands; compiled on a TPU, interpreted elsewhere (``interpret``, as
+    ``kernels.interpret_mode`` reads it)."""
+    k, n = rhs.shape[1:]
+    tiling = (min(GMM_ROWS, lhs.shape[0]), _tile(k), _tile(n))
+    return megablox.gmm(lhs, rhs, group_sizes, lhs.dtype, tiling, None, None,
+                        False, interpret_mode(interpret))
+
+
+def moe_ffn_held(x: jax.Array, weights: dict, cfg: MoEConfig, act: str = "silu"):
+    """The held experts' part of a DeepSeek-V3 expert layer, plus the shared
+    experts, for x (B, S, d).
+
+    ``weights``: ``router`` (d, E) over all experts; ``we1``/``we3``
+    (count, d, F) and ``we2`` (count, F, d) for the held experts
+    ``first .. first + count - 1``; ``ws1``/``ws3`` (d, Fs) and ``ws2``
+    (Fs, d) for the shared experts.  Returns (out (B, S, d), balance loss,
+    counters): ``rows``, the assignments routed to the held experts, and
+    ``max_load``, the largest held expert's rows over their mean."""
+    b, s, d = x.shape
+    t, k = b * s, cfg.top_k
+    first, count = cfg.experts_held
+    a = act_fn(act)
+    xt = x.reshape(t, d)
+    with jax.named_scope("moe_route"):
+        logits = xt.astype(jnp.float32) @ weights["router"].astype(jnp.float32)
+        w, idx, aux = route_held(logits, cfg, s)
+        local = idx.reshape(-1) - first
+        held = (local >= 0) & (local < count)
+        key = jnp.where(held, local, count)  # the rest sort last, as group `count`
+        order = jnp.argsort(key, stable=True)
+        rows = t * k
+        pad = -rows % min(GMM_ROWS, rows)
+        sizes = jnp.bincount(key, length=count + 1).astype(jnp.int32)
+        sizes = sizes.at[count].add(pad)
+        tok = order // k  # the token of each sorted assignment
+        xs = jnp.take(xt, tok, axis=0)
+        if pad:
+            xs = jnp.concatenate([xs, jnp.zeros((pad, d), xs.dtype)])
+    with jax.named_scope("moe_experts"):
+        h = a(gmm(xs, weights["we1"], sizes)) * gmm(xs, weights["we3"], sizes)
+        ys = gmm(h, weights["we2"], sizes)[:rows]
+    with jax.named_scope("moe_route"):
+        # back to (token, slot) order, weighted; the rest's rows are zero
+        inv = jnp.zeros((rows,), order.dtype).at[order].set(
+            jnp.arange(rows, dtype=order.dtype))
+        y = jnp.take(ys, inv, axis=0).reshape(t, k, d)
+        gate = jnp.where(held, w.reshape(-1), 0.0).reshape(t, k)
+        out = jnp.einsum("tkd,tk->td", y, gate.astype(y.dtype))
+    if cfg.shared_d_ff:
+        with jax.named_scope("moe_shared"):
+            hs = a(xt @ weights["ws1"]) * (xt @ weights["ws3"])
+            out = out + hs @ weights["ws2"]
+    n_held = jnp.sum(sizes[:count]).astype(jnp.float32)
+    counters = {"rows": n_held,
+                "max_load": jnp.max(sizes[:count]).astype(jnp.float32)
+                * count / jnp.maximum(n_held, 1.0)}
+    return out.reshape(b, s, d), aux, counters

@@ -2,7 +2,12 @@
 
 Supports the five assigned LM architectures: GQA (with kv-replication when
 n_kv_heads < tp), optional QKV bias (qwen2), sliding-window/global layer
-interleaving (gemma3), and MoE FFN (granite/qwen2-moe).
+interleaving (gemma3), and MoE FFN (granite/qwen2-moe); and the DeepSeek-V3
+block (Moonlight): multi-head latent attention (MLA), leading dense layers
+before the expert layers, and an expert layer that holds a chip's share of
+the routed experts (``models/moe.moe_ffn_held``).  That block trains at
+``tp`` 1 only; its stack is two scanned groups with a tree each
+(``params["dense"]``, then ``params["layers"]``).
 
 Tensor-parallel layout over the ``model`` axis (size ``tp``):
   * q/o projections: heads sharded ``tp_attn = min(tp, n_heads)`` ways; if
@@ -42,7 +47,7 @@ from repro.models.common import (
     rms_norm,
     split_keys,
 )
-from repro.models.moe import MoEConfig, moe_ffn
+from repro.models.moe import MoEConfig, moe_ffn, moe_ffn_held
 
 
 @dataclasses.dataclass(frozen=True)
@@ -72,6 +77,29 @@ class TransformerConfig:
     # the sequence dim; block psums become all-gather/psum-scatter conjugate
     # pairs (same wire bytes, 1/tp activation memory, no redundant norms).
     seq_parallel: bool = False
+    # multi-head latent attention (DeepSeek-V2/V3): kv_lora_rank > 0 turns it
+    # on; q and k heads are qk_nope_head_dim + qk_rope_head_dim wide, v heads
+    # v_head_dim; head_dim, n_kv_heads and qkv_bias are then unused
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    latent_eps: float = 1e-6  # the latent RMSNorm's epsilon
+    # leading dense layers (of width d_ff) before the MoE layers
+    first_k_dense: int = 0
+
+    def __post_init__(self):
+        held = self.moe is not None and self.moe.experts_held is not None
+        if (self.first_k_dense or held) and not self.kv_lora_rank:
+            raise ValueError(f"{self.name}: a dense prefix and held experts "
+                             f"come with the DeepSeek-V3 block (MLA)")
+
+    @property
+    def deepseek_block(self) -> bool:
+        """The DeepSeek-V3 block (MLA, with a dense prefix and held experts
+        where set): the two-group stack, which trains at tp 1 only and has
+        no prefill or decode."""
+        return self.kv_lora_rank > 0
 
     # ---- TP derived quantities -------------------------------------
     def tp_attn(self, tp: int) -> int:
@@ -103,6 +131,9 @@ class TransformerConfig:
 
     def param_count(self) -> int:
         """Exact parameter count (excluding vocab padding)."""
+        if self.deepseek_block:
+            return sum(math.prod(x.shape) for x in jax.tree.leaves(
+                jax.eval_shape(lambda: init_params(self, jax.random.PRNGKey(0)))))
         d, hd = self.d_model, self.head_dim
         attn = d * self.n_heads * hd + 2 * d * self.n_kv_heads * hd + self.n_heads * hd * d
         if self.qkv_bias:
@@ -123,6 +154,12 @@ class TransformerConfig:
             return self.param_count()
         d = self.d_model
         m = self.moe
+        if m.experts_held is not None:  # the held experts' expected share
+            count = m.experts_held[1]
+            held = 3 * d * m.d_ff_expert * count
+            active = 3 * d * m.d_ff_expert * m.top_k * count / m.n_experts
+            return int(self.param_count() - (self.n_layers - self.first_k_dense)
+                       * (held - active))
         full_ffn = d * m.n_experts + 3 * d * m.d_ff_expert * m.n_experts
         act_ffn = d * m.n_experts + 3 * d * (m.d_ff_expert * m.top_k + m.shared_d_ff)
         return self.param_count() - self.n_layers * (full_ffn - act_ffn) + (
@@ -134,8 +171,66 @@ class TransformerConfig:
 # parameters
 # ---------------------------------------------------------------------------
 
+def _init_deepseek(cfg: TransformerConfig, key) -> dict:
+    """The DeepSeek-V3 block's tree: ``dense`` (the first ``first_k_dense``
+    layers, SwiGLU of width ``d_ff``) and ``layers`` (the MoE layers), each
+    stacked on its own leading axis."""
+    d, H, pdt = cfg.d_model, cfg.n_heads, cfg.param_dtype
+    r, dr, dv = cfg.kv_lora_rank, cfg.qk_rope_head_dim, cfg.v_head_dim
+    dqk, dkv = cfg.qk_nope_head_dim + dr, cfg.qk_nope_head_dim + dv
+    kd, km, ke, kh = jax.random.split(key, 4)
+
+    def group(n, moe, key):
+        ks = iter(split_keys(key, 16))
+        lp = {"ln1": jnp.zeros((n, d), pdt), "ln2": jnp.zeros((n, d), pdt),
+              "wq": dense_init(next(ks), (n, d, H * dqk), d, pdt),
+              "wkv_a": dense_init(next(ks), (n, d, r + dr), d, pdt),
+              "kv_ln": jnp.zeros((n, r), pdt),
+              "wkv_b": dense_init(next(ks), (n, r, H * dkv), r, pdt),
+              "wo": dense_init(next(ks), (n, H * dv, d), H * dv, pdt)}
+        if not moe:
+            lp["w1"] = dense_init(next(ks), (n, d, cfg.d_ff), d, pdt)
+            lp["w3"] = dense_init(next(ks), (n, d, cfg.d_ff), d, pdt)
+            lp["w2"] = dense_init(next(ks), (n, cfg.d_ff, d), cfg.d_ff, pdt)
+            return lp
+        m = cfg.moe
+        count, f = m.experts_held[1], m.d_ff_expert
+        lp["router"] = dense_init(next(ks), (n, d, m.n_experts), d, jnp.float32)
+        lp["we1"] = dense_init(next(ks), (n, count, d, f), d, pdt)
+        lp["we3"] = dense_init(next(ks), (n, count, d, f), d, pdt)
+        lp["we2"] = dense_init(next(ks), (n, count, f, d), f, pdt)
+        if m.shared_d_ff:
+            fs = m.shared_d_ff
+            lp["ws1"] = dense_init(next(ks), (n, d, fs), d, pdt)
+            lp["ws3"] = dense_init(next(ks), (n, d, fs), d, pdt)
+            lp["ws2"] = dense_init(next(ks), (n, fs, d), fs, pdt)
+        return lp
+
+    vp = cfg.vocab_padded(1)
+    params = {
+        "embed": embed_init(ke, (vp, d), pdt),
+        "layers": group(cfg.n_layers - cfg.first_k_dense, cfg.moe is not None,
+                        km),
+        "ln_f": jnp.zeros((d,), pdt),
+        "head": embed_init(kh, (vp, d), pdt),
+    }
+    if cfg.first_k_dense:
+        params["dense"] = group(cfg.first_k_dense, False, kd)
+    return params
+
+
+def _tp1_only(cfg: TransformerConfig, tp: int):
+    if tp > 1:
+        raise ValueError(f"{cfg.name}: the DeepSeek-V3 block (MLA, held "
+                         f"experts) trains at tp 1 only; the mesh has a "
+                         f"model axis of {tp}")
+
+
 def init_params(cfg: TransformerConfig, key, tp: int = 1) -> dict:
     """Global param arrays (the duplicated q/o layout is materialized)."""
+    if cfg.deepseek_block:
+        _tp1_only(cfg, tp)
+        return _init_deepseek(cfg, key)
     L, d, hd = cfg.n_layers, cfg.d_model, cfg.head_dim
     R = cfg.attn_replicas(tp)
     vp = cfg.vocab_padded(tp)
@@ -197,7 +292,15 @@ def init_params(cfg: TransformerConfig, key, tp: int = 1) -> dict:
     }
 
 
+def _tp1_tree(cfg: TransformerConfig, leaf) -> dict:
+    return jax.tree.map(lambda _: leaf, jax.eval_shape(
+        lambda: _init_deepseek(cfg, jax.random.PRNGKey(0))))
+
+
 def make_param_specs(cfg: TransformerConfig, tp: int, axis: str = "model") -> dict:
+    if cfg.deepseek_block:
+        _tp1_only(cfg, tp)
+        return _tp1_tree(cfg, P())
     M = axis if tp > 1 else None
     kvs = cfg.kv_sharded(tp)
     kv = P(None, None, M) if kvs else P()
@@ -254,6 +357,9 @@ def grad_sync(cfg: TransformerConfig, tp: int) -> dict:
                     underlying head weights follow the same trajectory as
                     the non-duplicated model.
     """
+    if cfg.deepseek_block:
+        _tp1_only(cfg, tp)
+        return _tp1_tree(cfg, "none")
     R = cfg.attn_replicas(tp)
     rep = "psum_model" if tp > 1 else "none"
     qsync = f"scale_{R}" if R > 1 else "none"
@@ -340,7 +446,8 @@ def _kv_for_local_q(k, v, cfg: TransformerConfig, dist: Dist, tp: int):
 def _chunked_attention(q, k, v, cfg: TransformerConfig, is_global, q0: int = 0):
     """Causal (optionally windowed) attention, scanned over q chunks.
 
-    q: (B, Sq, H, hd); k/v: (B, Sk, H, hd) already per-q-head.
+    q/k: (B, Sq|Sk, H, hd); v: (B, Sk, H, hd or another head size) already
+    per-q-head; the scale is 1/sqrt(hd).
     ``is_global`` may be a traced bool (layer-type select inside scan).
     q0 = absolute position of q[0] (prefill continuation unused: 0).
     """
@@ -370,7 +477,7 @@ def _chunked_attention(q, k, v, cfg: TransformerConfig, is_global, q0: int = 0):
         return carry, out
 
     _, outs = lax.scan(chunk, None, (jnp.arange(n_chunks), jnp.swapaxes(qr, 0, 1)))
-    out = jnp.swapaxes(outs, 0, 1).reshape(b, sq, h, hd)
+    out = jnp.swapaxes(outs, 0, 1).reshape(b, sq, h, v.shape[-1])
     return out
 
 
@@ -379,13 +486,14 @@ def _attention(q, k, v, cfg: TransformerConfig, is_global, q0: int = 0):
 
     On a TPU, a global causal layer over a whole sequence from position 0
     whose length and head size the flash kernel tiles
-    (``kernels.attention.kernel_fits``) runs the kernel: its scores stay in
-    VMEM and the blocks above the diagonal are skipped. Everything else
+    (``kernels.attention.kernel_fits``, padding any head size up to 256)
+    runs the kernel: its scores stay in VMEM and the blocks above the
+    diagonal are skipped. Everything else
     (windowed layers, ``q0 > 0``, shapes that do not tile, other backends)
     takes ``_chunked_attention``."""
     sq, hd = q.shape[1], q.shape[3]
     if (not interpret_mode() and cfg.sliding_window is None and q0 == 0
-            and k.shape[1] == sq and kernel_fits(sq, hd)):
+            and k.shape[1] == sq and kernel_fits(sq, hd, v.shape[3])):
         with jax.named_scope("attn_kernel"):
             return causal_attention(q, k, v)
     return _chunked_attention(q, k, v, cfg, is_global, q0)
@@ -406,11 +514,44 @@ def _attn_block(x, lp, cfg: TransformerConfig, dist: Dist, tp: int, is_global,
     return out.astype(x.dtype)
 
 
-def _ffn_block(x, lp, cfg: TransformerConfig, dist: Dist, combine=None):
-    """Dense or MoE FFN; returns (out, aux_loss)."""
+def _mla_block(x, lp, cfg: TransformerConfig, positions):
+    """Multi-head latent attention (DeepSeek-V2/V3, ``q_lora_rank`` null).
+
+    ``q = h W_q``, split per head into ``q_nope`` (``qk_nope_head_dim``) and
+    ``q_pe`` (``qk_rope_head_dim``); ``[c_kv | k_pe] = h W_kva``; ``c_kv``
+    through an RMSNorm; ``[k_nope | v] = c_kv W_kvb`` per head; RoPE on
+    ``q_pe`` and on the one ``k_pe`` that all heads share; causal softmax at
+    scale ``1/sqrt(qk_nope_head_dim + qk_rope_head_dim)``; output ``W_o``.
+
+    RoPE here rotates the two halves of the 64 rope dims (x[:32] with
+    x[32:]), not interleaved pairs (x[0::2] with x[1::2]) as the published
+    modeling code does: published ``W_q``'s rope columns and ``W_kva``'s
+    ``k_pe`` columns would be permuted (evens first, then odds) to load here.
+    The latent projections run under the scope ``mla_latent``."""
+    b, s, _ = x.shape
+    H, r = cfg.n_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    with jax.named_scope("mla_latent"):
+        q = (x @ lp["wq"]).reshape(b, s, H, dn + dr)
+        kva = x @ lp["wkv_a"]
+        c_kv = rms_norm(kva[..., :r], lp["kv_ln"], cfg.latent_eps)
+        kv = (c_kv @ lp["wkv_b"]).reshape(b, s, H, dn + dv)
+    q_pe = apply_rope(q[..., dn:], positions, cfg.rope_theta)
+    k_pe = apply_rope(kva[..., None, r:], positions, cfg.rope_theta)
+    q = jnp.concatenate([q[..., :dn], q_pe], axis=-1)
+    k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(k_pe, (b, s, H, dr))],
+                        axis=-1)
+    out = _attention(q, k, kv[..., dn:], cfg, jnp.bool_(True))
+    return (out.reshape(b, s, H * dv) @ lp["wo"]).astype(x.dtype)
+
+
+def _ffn_block(x, lp, cfg: TransformerConfig, dist: Dist, combine=None,
+               moe: bool | None = None):
+    """Dense or MoE FFN; returns (out, aux_loss).  ``moe=False`` takes the
+    dense SwiGLU in a configuration whose other layers are MoE."""
     b, s, d = x.shape
     combine = combine or dist.psum_model
-    if cfg.moe is None:
+    if cfg.moe is None or moe is False:
         a = jax.nn.silu if cfg.act == "silu" else jax.nn.gelu
         h = a(x @ lp["w1"]) * (x @ lp["w3"])
         out = h @ lp["w2"]
@@ -427,7 +568,12 @@ def _ffn_block(x, lp, cfg: TransformerConfig, dist: Dist, combine=None):
     return out.astype(x.dtype), aux
 
 
-def _layer(x, lp, layer_idx, cfg: TransformerConfig, dist: Dist, tp: int, positions):
+def _layer(x, lp, layer_idx, cfg: TransformerConfig, dist: Dist, tp: int,
+           positions, moe: bool | None = None):
+    """One decoder layer; returns (x, aux_loss, counters).  ``counters``
+    holds the held experts' ``rows`` and ``max_load`` on that path and is
+    empty elsewhere."""
+    moe = cfg.moe is not None if moe is None else moe
     is_global = (
         jnp.bool_(True)
         if (cfg.global_every <= 0 or cfg.sliding_window is None)
@@ -447,41 +593,59 @@ def _layer(x, lp, layer_idx, cfg: TransformerConfig, dist: Dist, tp: int, positi
 
     h = block_in(x)
     with jax.named_scope("attn"):
-        a_out = _attn_block(h, lp, cfg, dist, tp, is_global, positions,
-                            combine=block_out)
+        if cfg.deepseek_block:
+            a_out = _mla_block(h, lp, cfg, positions)
+        else:
+            a_out = _attn_block(h, lp, cfg, dist, tp, is_global, positions,
+                                combine=block_out)
     x = x + a_out
     h = rms_norm(x, lp["ln2"], cfg.eps)
     if sp:
         h = dist.all_gather_model(h, axis=1)
+    counters = {}
     with jax.named_scope("mlp"):
-        f, aux = _ffn_block(h, lp, cfg, dist, combine=block_out)
-    return x + f, aux
+        if moe and cfg.moe.experts_held is not None:
+            f, aux, counters = moe_ffn_held(h, lp, cfg.moe, cfg.act)
+        else:
+            f, aux = _ffn_block(h, lp, cfg, dist, combine=block_out, moe=moe)
+    return x + f, aux, counters
 
 
 def forward(params, tokens, cfg: TransformerConfig, dist: Dist, tp: int):
-    """tokens (B, S) -> hidden (B, S or S/tp if seq_parallel, d) + aux."""
+    """tokens (B, S) -> (hidden (B, S or S/tp if seq_parallel, d), aux loss,
+    counters).  The layers run as one remat'd ``lax.scan`` per stacked
+    group: ``params["dense"]`` (the DeepSeek-V3 block's dense prefix) where
+    present, then ``params["layers"]``.  ``counters`` holds each held-experts
+    layer's ``rows`` and ``max_load`` (stacked over the layers), and is
+    empty on every other path."""
     b, s = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(s), (b, s))
     sp = cfg.seq_parallel and dist.model_axis is not None
     x = _embed(params, tokens, cfg, dist, scatter_seq=sp)
+    aux = jnp.float32(0.0)
+    groups = [("dense", False, 0)] if "dense" in params else []
+    groups.append(("layers", cfg.moe is not None, cfg.first_k_dense))
+    counters = {}
+    for name, moe, first in groups:
+        n = jax.tree.leaves(params[name])[0].shape[0]
 
-    def body(carry, inputs):
-        x, aux = carry
-        lp, li = inputs
-        x, a = _layer(x, lp, li, cfg, dist, tp, positions)
-        return (x, aux + a), None
+        def body(carry, inputs, moe=moe):
+            x, aux = carry
+            lp, li = inputs
+            x, a, cnt = _layer(x, lp, li, cfg, dist, tp, positions, moe=moe)
+            return (x, aux + a), cnt
 
-    body_fn = jax.checkpoint(body) if cfg.remat else body
-    (x, aux), _ = lax.scan(
-        body_fn, (x, jnp.float32(0.0)), (params["layers"], jnp.arange(cfg.n_layers))
-    )
-    return x, aux
+        body_fn = jax.checkpoint(body) if cfg.remat else body
+        (x, aux), cnt = lax.scan(body_fn, (x, aux),
+                                 (params[name], jnp.arange(first, first + n)))
+        counters.update(cnt)
+    return x, aux, counters
 
 
 def lm_loss(params, tokens, labels, cfg: TransformerConfig, dist: Dist, tp: int):
     """Distributed-softmax CE over the vocab-sharded head. Returns scalar
     per-worker mean loss (caller pmeans over workers)."""
-    x, aux = forward(params, tokens, cfg, dist, tp)
+    x, aux, counters = forward(params, tokens, cfg, dist, tp)
     with jax.named_scope("lm_head"):
         x = rms_norm(x, params["ln_f"], cfg.eps)
         if cfg.seq_parallel and dist.model_axis is not None:
@@ -507,12 +671,22 @@ def lm_loss(params, tokens, labels, cfg: TransformerConfig, dist: Dist, tp: int)
             dist.psum_model(jnp.sum(jnp.exp(logits - mx[..., None]), axis=-1))
         )
         ce = jnp.mean(lse - lab_logit)
-    return ce + aux, {"ce": ce, "aux": aux}
+    met = {"ce": ce, "aux": aux}
+    if counters:  # the held experts: rows and load per layer, and the loss
+        met.update(moe_rows=jnp.mean(counters["rows"]),
+                   moe_max_load=jnp.max(counters["max_load"]), moe_aux=aux)
+    return ce + aux, met
 
 
 # ---------------------------------------------------------------------------
 # serving: prefill + decode with a sequence-sharded KV cache
 # ---------------------------------------------------------------------------
+
+def _no_serving(cfg: TransformerConfig):
+    if cfg.deepseek_block:
+        raise ValueError(f"{cfg.name}: the DeepSeek-V3 block (MLA, held "
+                         f"experts) has no prefill or decode path")
+
 
 def init_cache(cfg: TransformerConfig, batch_local: int, max_seq: int, tp: int):
     """Per-device cache: (L, B, S/tp, Hkv, hd) seq-sharded over model."""
@@ -534,6 +708,7 @@ def _full_kv(k, v, cfg, dist: Dist, tp: int):
 
 def prefill(params, tokens, cfg: TransformerConfig, dist: Dist, tp: int, max_seq: int):
     """Returns (greedy next-token ids (B,), cache filled with S tokens)."""
+    _no_serving(cfg)
     b, s = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(s), (b, s))
     x = _embed(params, tokens, cfg, dist)
@@ -654,6 +829,7 @@ def _decode_attn_distributed(
 def decode_step(params, token, cache, pos, cfg: TransformerConfig, dist: Dist, tp: int):
     """One greedy decode step.  token (B,) int32; pos: scalar count of tokens
     already in the cache.  Returns (next_token (B,), new cache)."""
+    _no_serving(cfg)
     b = token.shape[0]
     x = _embed(params, token[:, None], cfg, dist)[:, 0]  # (B, d)
     sloc = cache["k"].shape[2]
@@ -722,6 +898,7 @@ def decode_step_unrolled(
     params, token, caches, pos, cfg: TransformerConfig, dist: Dist, tp: int
 ):
     """Decode with heterogeneous per-layer caches (gemma3 long-context)."""
+    _no_serving(cfg)
     b = token.shape[0]
     x = _embed(params, token[:, None], cfg, dist)[:, 0]
     new_caches = []

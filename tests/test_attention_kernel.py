@@ -91,9 +91,12 @@ def test_attention_dispatch(monkeypatch, case, seq, q0, cfg_kw, tpu, kernel):
 
 @pytest.mark.parametrize("seq, head_dim, fits", [
     (4096, 128, True), (512, 128, True), (256, 256, True), (128, 128, True),
-    (640, 128, False), (96, 128, False), (4096, 64, False),
+    (640, 128, False), (96, 128, False), (4096, 64, True), (8192, 192, True),
+    (4096, 320, False),
 ])
 def test_kernel_fits(seq, head_dim, fits):
+    """Any head size that pads to at most 256 lanes fits; the sequence must
+    tile."""
     assert kernel_fits(seq, head_dim) is fits
     assert kernel_block(seq) == min(512, seq)
 
@@ -101,7 +104,7 @@ def test_kernel_fits(seq, head_dim, fits):
 @pytest.mark.parametrize("shapes, dtypes, block", [
     (((1, 256, 4, 128), (1, 256, 3, 128), (1, 256, 3, 128)), None, None),
     (((1, 256, 4, 128), (1, 128, 2, 128), (1, 128, 2, 128)), None, None),
-    (((1, 256, 4, 64), (1, 256, 2, 64), (1, 256, 2, 64)), None, None),
+    (((1, 256, 4, 64), (1, 256, 2, 128), (1, 256, 2, 128)), None, None),
     (((1, 256, 4, 128), (1, 256, 2, 128), (1, 256, 2, 128)), None, 96),
     (((1, 256, 4, 128),) * 3, (jnp.bfloat16, jnp.float32, jnp.float32), None),
 ], ids=["heads", "kv_len", "head_dim", "block", "dtype"])
@@ -110,3 +113,40 @@ def test_kernel_rejects(shapes, dtypes, block):
     args = [jnp.zeros(s, d) for s, d in zip(shapes, dtypes)]
     with pytest.raises(ValueError):
         causal_attention(*args, block=block)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+def test_kernel_with_latent_head_sizes(dtype):
+    """Latent attention's heads: q and k of 192, v of 128, padded to 256
+    inside the kernel and scaled by 1/sqrt(192); the output and dq, dk, dv
+    against the oracle at the same tolerances as above."""
+    seq, dqk, dv = 256, 192, 128
+    ks = jax.random.split(jax.random.PRNGKey(7), 4)
+    q = jax.random.normal(ks[0], (1, seq, HEADS, dqk), dtype)
+    k = jax.random.normal(ks[1], (1, seq, HEADS, dqk), dtype)
+    v = jax.random.normal(ks[2], (1, seq, HEADS, dv), dtype)
+    do = jax.random.normal(ks[3], (1, seq, HEADS, dv), dtype)
+    got = _out_and_grads(lambda q, k, v: causal_attention(q, k, v, block=128),
+                         (q, k, v), do)
+    oracle = _out_and_grads(causal_attention_ref, (q, k, v), do)
+    assert got[0].shape == (1, seq, HEADS, dv)
+    for name, g, o in zip(("out", "dq", "dk", "dv"), got, oracle):
+        assert g.shape == o.shape and g.dtype == o.dtype, name
+        assert _rel_err(g, o) < TOL[dtype], (name, _rel_err(g, o))
+
+
+def test_latent_attention_at_8k_takes_the_kernel_on_a_tpu(monkeypatch):
+    """At the Moonlight cell's shapes (S 8192, Dqk 192, Dv 128) the rule
+    sends attention to the kernel on a TPU, never to the chunked path; the
+    kernel is replaced here, so nothing runs at that size."""
+    taken = []
+    monkeypatch.setattr(transformer, "interpret_mode", lambda: False)
+    monkeypatch.setattr(transformer, "causal_attention",
+                        lambda q, k, v: taken.append("kernel") or v)
+    monkeypatch.setattr(transformer, "_chunked_attention",
+                        lambda *a, **kw: taken.append("chunked"))
+    q = jax.ShapeDtypeStruct((1, 8192, 16, 192), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((1, 8192, 16, 128), jnp.bfloat16)
+    out = jax.eval_shape(lambda q, k, v: transformer._attention(
+        q, k, v, _cfg(), jnp.bool_(True)), q, q, v)
+    assert taken == ["kernel"] and out.shape == v.shape

@@ -15,7 +15,8 @@ from repro.optim.optimizers import adamw, momentum
 
 def test_cell_matrix_is_complete():
     cells = list_cells()
-    assert len(cells) == 40  # 5 LM x 4 + 1 GNN x 4 + 4 recsys x 4
+    # 5 LM x 4 + 1 GNN x 4 + 4 recsys x 4 + Moonlight's one training cell
+    assert len(cells) == 41
     skips = [
         (a, s) for a, s in cells
         if get_arch(a).cell(s).skip_reason is not None

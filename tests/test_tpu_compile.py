@@ -22,6 +22,7 @@ from repro.kernels.embedding_bag.ops import embedding_bag
 from repro.kernels.fused_agg_opt.ops import fused_aggregate_update
 from repro.kernels.quant.ops import dequantize_chunks, quantize_chunks
 from repro.kernels.wire_path.ops import fused_wire_update
+from repro.models.moe import gmm
 from repro.optim.optimizers import adamw, momentum
 
 FLAT = 25_559_040  # ResNet-50's flat chunk space
@@ -30,6 +31,10 @@ CHUNK = 8192
 CODEC_ELEMS = CHUNK * 64
 TABLE_ROWS, EMB_DIM, BAGS, BAG_LEN = 100_000, 128, 256, 8
 SEQ, HEADS, HEAD_DIM = 4096, 16, 128  # the LM cell's attention, one sequence
+# the Moonlight cell: latent attention at 8k (q/k heads of 192, v of 128), and
+# the held experts' grouped matmul over a microbatch's 98,304 assignments
+MLA_SEQ, MLA_QK, MLA_V = 8192, 192, 128
+ROWS, D_MODEL, D_EXPERT, HELD = 98_304, 2048, 1408, 8
 
 
 @pytest.fixture(scope="module")
@@ -121,3 +126,32 @@ def test_causal_attention_compiles_for_v5e(sds, grad):
     qkv = [sds((1, SEQ, HEADS, HEAD_DIM), jnp.bfloat16) for _ in range(3)]
     _assert_kernel_compiles(
         jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd, *qkv)
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+def test_latent_attention_compiles_for_v5e(sds, grad):
+    def fwd(q, k, v):
+        return causal_attention(q, k, v, interpret=False)
+
+    def loss(q, k, v):
+        return jnp.sum(fwd(q, k, v).astype(jnp.float32))
+
+    q = sds((1, MLA_SEQ, HEADS, MLA_QK), jnp.bfloat16)
+    v = sds((1, MLA_SEQ, HEADS, MLA_V), jnp.bfloat16)
+    _assert_kernel_compiles(
+        jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd, q, q, v)
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+def test_grouped_matmul_compiles_for_v5e(sds, grad):
+    def fwd(x, w, sizes):
+        return gmm(x, w, sizes, interpret=False)
+
+    def loss(x, w, sizes):
+        return jnp.sum(fwd(x, w, sizes).astype(jnp.float32))
+
+    _assert_kernel_compiles(
+        jax.grad(loss, argnums=(0, 1)) if grad else fwd,
+        sds((ROWS, D_MODEL), jnp.bfloat16),
+        sds((HELD, D_MODEL, D_EXPERT), jnp.bfloat16),
+        sds((HELD + 1,), jnp.int32))
