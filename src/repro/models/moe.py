@@ -11,7 +11,11 @@ grouped matmul over the held experts (``gmm``, JAX's megablox kernel) does
 work that follows the rows routed to them.  Its buffers are static and
 sized for the worst case, every assignment held.  What the experts held on
 other chips would add is left out; the shared experts are computed on every
-chip.  :func:`moe_ffn_held`.
+chip.  The rows move to and from the sorted order by gathers whose backward
+is a gather too, through the inverse permutation (:func:`take_rows`), and
+the router's pick of its top-k scores differentiates by a compare
+(:func:`pick_columns`): the layer's gradient scatters no floating-point
+rows.  :func:`moe_ffn_held`.
 
 **Capacity, tensor-parallel** (``experts_held`` unset; granite and
 qwen2-moe), :func:`moe_ffn`:
@@ -32,6 +36,7 @@ scatter-gather through an (E, C, d) buffer.
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -154,6 +159,31 @@ def moe_ffn(
 # held experts, dropless (DeepSeek-V3)
 # ---------------------------------------------------------------------------
 
+def pick_columns(s: jax.Array, idx: jax.Array) -> jax.Array:
+    """``jnp.take_along_axis(s, idx, axis=-1)`` for ``s`` (T, E) and ``idx``
+    (T, k) of distinct columns in each row (top-k's).  The backward places
+    each row's ``k`` cotangents by comparing ``idx`` with the column ids, where
+    autodiff of the take would scatter-add T * k scalars into (T, E)."""
+    return _pick_columns(s, idx, s.shape[-1])
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _pick_columns(s, idx, e):
+    return jnp.take_along_axis(s, idx, axis=-1)
+
+
+def _pick_columns_fwd(s, idx, e):
+    return jnp.take_along_axis(s, idx, axis=-1), idx
+
+
+def _pick_columns_bwd(e, idx, ct):
+    hit = idx[..., None] == jnp.arange(e, dtype=idx.dtype)  # (T, k, E)
+    return jnp.sum(jnp.where(hit, ct[..., None], 0), axis=1), None
+
+
+_pick_columns.defvjp(_pick_columns_fwd, _pick_columns_bwd)
+
+
 def route_held(logits: jax.Array, cfg: MoEConfig, seq_len: int):
     """logits (T, E) f32 over all experts -> (weights (T, k), experts (T, k),
     sequence-wise balance loss).
@@ -178,7 +208,7 @@ def route_held(logits: jax.Array, cfg: MoEConfig, seq_len: int):
     pick = s if cfg.select_bias is None else s + jnp.asarray(
         cfg.select_bias, jnp.float32)
     _, idx = jax.lax.top_k(pick, k)
-    w = jnp.take_along_axis(s, idx, axis=-1)
+    w = pick_columns(s, idx)
     if cfg.norm_topk:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
     w = w * cfg.routed_scale
@@ -208,6 +238,33 @@ def gmm(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
                         False, interpret_mode(interpret))
 
 
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def take_rows(src: jax.Array, fwd: jax.Array, bwd: jax.Array, k: int):
+    """``jnp.take(src, fwd, axis=0)`` where ``fwd`` lists every row of
+    ``src`` exactly ``k`` times, row ``r`` at the positions
+    ``bwd[r * k : (r + 1) * k]``.  The backward gathers the cotangent's rows
+    through ``bwd`` and sums each row's ``k`` in float32, where autodiff of
+    the take would scatter-add them (on a v5e the held layer's two
+    scatter-adds of 98,304 rows of 2,048 took 7-11 ms a call, about 10x
+    their HBM bound; PERF.md)."""
+    return jnp.take(src, fwd, axis=0)
+
+
+def _take_rows_fwd(src, fwd, bwd, k):
+    return jnp.take(src, fwd, axis=0), bwd
+
+
+def _take_rows_bwd(k, bwd, ct):
+    parts = [jnp.take(ct, bwd[j::k], axis=0, mode="clip") for j in range(k)]
+    if k == 1:
+        return parts[0], None, None
+    acc = sum(p.astype(jnp.float32) for p in parts)
+    return acc.astype(ct.dtype), None, None
+
+
+take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
 def moe_ffn_held(x: jax.Array, weights: dict, cfg: MoEConfig, act: str = "silu"):
     """The held experts' part of a DeepSeek-V3 expert layer, plus the shared
     experts, for x (B, S, d).
@@ -235,7 +292,10 @@ def moe_ffn_held(x: jax.Array, weights: dict, cfg: MoEConfig, act: str = "silu")
         sizes = jnp.bincount(key, length=count + 1).astype(jnp.int32)
         sizes = sizes.at[count].add(pad)
         tok = order // k  # the token of each sorted assignment
-        xs = jnp.take(xt, tok, axis=0)
+        # each assignment's place in the sorted order
+        inv = jnp.zeros((rows,), order.dtype).at[order].set(
+            jnp.arange(rows, dtype=order.dtype))
+        xs = take_rows(xt, tok, inv, k)
         if pad:
             xs = jnp.concatenate([xs, jnp.zeros((pad, d), xs.dtype)])
     with jax.named_scope("moe_experts"):
@@ -243,9 +303,7 @@ def moe_ffn_held(x: jax.Array, weights: dict, cfg: MoEConfig, act: str = "silu")
         ys = gmm(h, weights["we2"], sizes)[:rows]
     with jax.named_scope("moe_route"):
         # back to (token, slot) order, weighted; the rest's rows are zero
-        inv = jnp.zeros((rows,), order.dtype).at[order].set(
-            jnp.arange(rows, dtype=order.dtype))
-        y = jnp.take(ys, inv, axis=0).reshape(t, k, d)
+        y = take_rows(ys, inv, order, 1).reshape(t, k, d)
         gate = jnp.where(held, w.reshape(-1), 0.0).reshape(t, k)
         out = jnp.einsum("tkd,tk->td", y, gate.astype(y.dtype))
     if cfg.shared_d_ff:

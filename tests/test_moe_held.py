@@ -9,6 +9,8 @@ arithmetic in another order, they agree to float32 rounding, and 1e-5 of
 the output's scale (relative) holds with room; the capacity path's drops
 move its output by a tenth or more of that scale.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -16,7 +18,8 @@ import pytest
 
 from repro.models import transformer as T
 from repro.models.common import Dist
-from repro.models.moe import MoEConfig, gmm, moe_ffn, moe_ffn_held, route_topk
+from repro.models.moe import (MoEConfig, gmm, moe_ffn, moe_ffn_held,
+                              pick_columns, route_topk, take_rows)
 
 D, F, FS = 128, 128, 256
 TOL = 1e-5  # float32 arithmetic in another order (see the module's docstring)
@@ -144,6 +147,94 @@ def test_eight_shares_add_up_to_the_uncut_layer():
                              [(e, e) for e in range(64)]).reshape(x.shape)
     assert _rel(total, uncut) < TOL
     assert _rel(uncut, plain) < TOL
+
+
+def _sorted_assignments(key, t, k, n_experts=16, held=(4, 4)):
+    """The layer's indices for t tokens routed to k experts each (drawn at
+    random, so a token may pick one expert twice): the assignments sorted by
+    held expert, the rest last; each sorted assignment's token; each
+    assignment's place in the sorted order; whether each sorted assignment
+    is held."""
+    experts = jax.random.randint(key, (t * k,), 0, n_experts)
+    local = experts - held[0]
+    is_held = (local >= 0) & (local < held[1])
+    order = jnp.argsort(jnp.where(is_held, local, held[1]), stable=True)
+    inv = jnp.zeros((t * k,), order.dtype).at[order].set(
+        jnp.arange(t * k, dtype=order.dtype))
+    return order, order // k, inv, is_held[order]
+
+
+def _gather_forms(form, t=48, k=6):
+    """(src, fwd, bwd, fan-in, cotangent) of the layer's two gathers: the
+    dispatch of each token to its k sorted assignments, whose cotangent is
+    zero on the rows of experts not held; the combine's permutation back."""
+    order, tok, inv, held_sorted = _sorted_assignments(jax.random.PRNGKey(10),
+                                                       t, k)
+    ct = jax.random.normal(jax.random.PRNGKey(11), (t * k, D))
+    if form == "dispatch":
+        src = jax.random.normal(jax.random.PRNGKey(12), (t, D))
+        assert not bool(jnp.all(held_sorted))  # some rows are not held
+        return src, tok, inv, k, jnp.where(held_sorted[:, None], ct, 0.0)
+    src = jax.random.normal(jax.random.PRNGKey(12), (t * k, D))
+    return src, inv, order, 1, ct
+
+
+@pytest.mark.parametrize("where", ["direct", "remat_scan"])
+@pytest.mark.parametrize("form", ["dispatch", "combine"])
+def test_take_rows_vjp_matches_autodiff_of_the_take(form, where):
+    """The gather's own backward (a gather through ``bwd``, summed over the
+    fan-in in float32) gives what autodiff of ``jnp.take`` gives, alone and
+    inside a ``jax.checkpoint``-ed ``lax.scan`` over two layers, as the
+    model runs it."""
+    src, fwd, bwd, k, ct = _gather_forms(form)
+    mine = lambda s, f, b: take_rows(s, f, b, k)  # noqa: E731
+    plain = lambda s, f, b: jnp.take(s, f, axis=0)  # noqa: E731
+    if where == "remat_scan":
+        layers = lambda a: jnp.stack([a, a[::-1]])  # noqa: E731
+        src, ct = jnp.stack([src, 2.0 * src]), layers(ct)
+        fwd, bwd = jnp.stack([fwd, fwd]), jnp.stack([bwd, bwd])
+
+        def scanned(fn):
+            body = jax.checkpoint(lambda c, xs: (c, fn(*xs)))
+            return lambda s, f, b: jax.lax.scan(body, None, (s, f, b))[1]
+
+        mine, plain = scanned(mine), scanned(plain)
+    got, g = _out_and_grads(lambda s, _: mine(s, fwd, bwd), src, 0.0, ct)
+    want, gp = _out_and_grads(lambda s, _: plain(s, fwd, bwd), src, 0.0, ct)
+    assert bool(jnp.all(got == want))
+    assert _rel(g[0], gp[0]) < 1e-6
+
+
+def test_pick_columns_vjp_matches_autodiff_of_take_along_axis():
+    s = jax.random.normal(jax.random.PRNGKey(13), (64, 16))
+    _, idx = jax.lax.top_k(s, 6)
+    ct = jax.random.normal(jax.random.PRNGKey(14), (64, 6))
+    got, g = _out_and_grads(lambda s, i: pick_columns(s, i), s, idx, ct)
+    want, gp = _out_and_grads(
+        lambda s, i: jnp.take_along_axis(s, i, axis=-1), s, idx, ct)
+    assert bool(jnp.all(got == want))
+    assert bool(jnp.all(g[0] == gp[0]))  # one term each: exact
+
+
+_SCATTER = re.compile(r'"stablehlo\.scatter"\(.*?\n\s*\}\) : \(tensor<([^>]*)>',
+                      re.S)
+
+
+def test_the_held_layer_gradient_scatters_no_floating_point_rows():
+    """No scatter of floating-point rows is left in the lowered gradient of
+    the layer (x and every weight): the gathers' backward gathers, the
+    router's pick compares.  What scatters is integer (the inverse
+    permutation, the experts' counts) or megablox's 1-D histogram of row
+    tiles in the grouped matmul's metadata."""
+    cfg = _cfg(top_k=6)
+    w = _weights(cfg, jax.random.PRNGKey(15))
+    x = jax.random.normal(jax.random.PRNGKey(16), (2, 32, D))
+    loss = lambda x, w: jnp.sum(moe_ffn_held(x, w, cfg)[0])  # noqa: E731
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(x, w).as_text()
+    operands = [t.split("x") for t in _SCATTER.findall(text)]
+    assert [str(x.size // D * 6), "i32"] in operands  # the inverse permutation
+    floats = [o for o in operands if re.fullmatch(r"b?f\d+", o[-1])]
+    assert all(len(o) == 2 for o in floats), floats  # 1-D only
 
 
 def test_grouped_matmul_against_ragged_dot_and_the_dense_loop():
